@@ -88,7 +88,11 @@ def flush_streaming_summary(results_path: str) -> str:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
     from .common import flush_results
+
+    enable_compile_cache()
 
     sections, errors = load_sections()
     for name, e in errors:

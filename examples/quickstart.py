@@ -4,8 +4,11 @@
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import BoxFilter, CubeGraphConfig, CubeGraphIndex
 from repro.core.workloads import ground_truth, make_dataset, recall
+
+enable_compile_cache()
 
 # 1. A dataset of (embedding, spatio-temporal metadata) pairs:
 #    5k objects, 48-d embeddings, metadata = (lon, lat) in [0,1]^2.

@@ -6,12 +6,15 @@ document store -> CubeGraph filtered retrieval -> LM generation.
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import CubeGraphConfig
 from repro.core.filters import BoxFilter
 from repro.core.workloads import make_dataset
 from repro.models import build_model, init_params
 from repro.serving.rag import Document, DocumentStore, RAGPipeline
+
+enable_compile_cache()
 
 # Corpus: 2000 geo-tagged "reports" (embedding + (lon, lat, t) + token span)
 x, s = make_dataset(2000, 32, 3, seed=0)

@@ -5,10 +5,13 @@ two query strategies (predetermined Alg. 3 vs on-the-fly Alg. 4).
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CubeGraphConfig, CubeGraphIndex
 from repro.core.workloads import (ground_truth, make_ball_filter,
                                   make_compose_filter, make_dataset,
                                   make_polygon_filter, recall)
+
+enable_compile_cache()
 
 # 3D metadata: (lon, lat, timestamp)
 x, s = make_dataset(n=6000, d=32, m=3, seed=1)

@@ -13,12 +13,15 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.pipeline import DataConfig, SyntheticTokenPipeline
 from repro.models import build_model, init_params
 from repro.models.common import ArchConfig
 from repro.training.checkpoint import CheckpointManager
 from repro.training.optimizer import OptConfig
 from repro.training.train_step import init_train_state, make_train_step
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)

@@ -59,10 +59,14 @@ def _topk_over_candidates(qv, qn, cand, x, norms, exclude, k, col_chunk, metric)
         ids = cand[:, i, :]                                   # [b, c]
         safe = jnp.maximum(ids, 0)
         xv = x[safe]                                          # [b, c, d]
+        # HIGHEST: the int8 path's exact fp32 rerank scores through here,
+        # and a TPU's default f32 matmul is one bf16 pass
+        ip = jnp.einsum("bcd,bd->bc", xv, qv,
+                        precision=jax.lax.Precision.HIGHEST)
         if metric == "l2":
-            d = norms[safe] - 2.0 * jnp.einsum("bcd,bd->bc", xv, qv) + qn[:, None]
+            d = norms[safe] - 2.0 * ip + qn[:, None]
         else:  # inner product (negated => smaller is better)
-            d = -jnp.einsum("bcd,bd->bc", xv, qv)
+            d = -ip
         bad = (ids < 0) | (ids == exclude[:, None])
         d = jnp.where(bad, INF, d)
         all_ids = jnp.concatenate([run_ids, ids], axis=1)

@@ -63,13 +63,14 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import Filter
 from ..kernels import (PAD_META, dispatch_trace_count, next_pow2,
                        quant_meta_rows, round_up, sharded_filtered_topk,
                        sharded_filtered_topk_grouped,
                        sharded_quant_filtered_topk)
+from ..kernels.ops import place_rows
 from ..obs.trace import NULL_TRACE, block_ready
 
 __all__ = ["BucketedShardPack", "PackView", "SegmentShardSource",
@@ -112,11 +113,10 @@ def make_shard_mesh(n_devices: Optional[int] = None) -> Mesh:
     code path is identical, which is how the sharded search is exercised in
     CI while production runs hand in a real multi-device mesh.
     """
-    from ..launch.mesh import mesh_compat_kwargs
     devs = jax.devices()
     n = len(devs) if n_devices is None else min(int(n_devices), len(devs))
     return Mesh(np.asarray(devs[:n]).reshape(n), ("shard",),
-                **mesh_compat_kwargs(1))
+                axis_types=(AxisType.Auto,))
 
 
 @dataclasses.dataclass
@@ -557,14 +557,10 @@ class BucketedShardPack:
     # -- placement -----------------------------------------------------
     def _place(self, arr: jnp.ndarray) -> jnp.ndarray:
         """(Re-)pin a bucket block's sharding after a functional update:
-        shard-axis partitioned when a mesh is attached and the row count
-        divides the device count — which :meth:`_init_slots` guarantees
-        for every bucket block it allocates (the check stays defensive)."""
-        if self.mesh is not None \
-                and int(arr.shape[0]) % self.mesh.devices.size == 0:
-            spec = P("shard", *([None] * (arr.ndim - 1)))
-            return jax.device_put(arr, NamedSharding(self.mesh, spec))
-        return arr
+        shard-axis partitioned when a mesh is attached.  :meth:`_init_slots`
+        makes every block's row count divide the device count; a count
+        that does not raises rather than leave the block on one device."""
+        return place_rows(arr, self.mesh)
 
     def _new_block(self, rows: int, cap: int):
         """Fresh zero/PAD device arrays for ``rows`` bucket rows, in the

@@ -1,7 +1,13 @@
-"""Pallas TPU kernels for CubeGraph's compute hot-spots (validated in
-interpret mode on CPU; see DESIGN.md §2.2).
+"""Pallas TPU kernels for CubeGraph's compute hot-spots.
+
+Each kernel call takes its mode from the backend
+(``filtered_topk.interpret_mode``): Mosaic on a TPU, the Pallas
+interpreter on the CPU, where ``tests/test_kernels.py`` checks them against
+the jnp oracles.  ``tests/test_chip_compile.py`` compiles the main-path
+kernels for a described v5e.
 
 - ``distance``      tiled pairwise distance matrix (MXU contraction)
+- ``graph_topk``    beam-step kernel + stitched per-bucket traversal
 - ``filtered_topk`` fused distance + spatio-temporal predicate + streaming
                     top-k (the paper's Fig. 3 aligned-traversal loop)
 - ``quant_topk``    fused *asymmetric-distance* filtered top-k over int8

@@ -9,10 +9,13 @@ the brute-force scan path.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .filtered_topk import interpret_mode
 
 __all__ = ["pairwise_dist_kernel_call"]
 
@@ -34,12 +37,15 @@ def _dist_kernel(q_ref, x_ref, o_ref, *, metric: str):
 
 @functools.partial(jax.jit, static_argnames=("metric", "tq", "tn", "interpret"))
 def pairwise_dist_kernel_call(q, x, metric: str = "l2", tq: int = 128,
-                              tn: int = 512, interpret: bool = True):
+                              tn: int = 512,
+                              interpret: Optional[bool] = None):
     """[bq, d] x [n, d] -> [bq, n] distances via a (bq/tq, n/tn) Pallas grid.
 
     Inputs must be pre-padded: bq % tq == 0, n % tn == 0, d % 128 == 0
     (see ``ops.pairwise_dist`` for the padding wrapper).
     """
+    if interpret is None:
+        interpret = interpret_mode()
     bq, d = q.shape
     n = x.shape[0]
     grid = (bq // tq, n // tn)

@@ -20,6 +20,7 @@ scratch initializes at j == 0 and the result is emitted at the last j
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,8 @@ _POS = 1e30
 
 
 def _filter_mask(meta, params, kind):
-    """meta [tn, mpad], params [4, mpad] -> bool [tn]."""
+    """meta [tn, mpad], params [4, mpad] -> bool [tn] (row layout: the
+    beam-step kernel and its jnp twin score gathered candidate rows)."""
     mpad = meta.shape[-1]
     in_box = jnp.all((meta >= params[0]) & (meta <= params[1]), axis=-1)
     mc = params[3, 1].astype(jnp.int32)
@@ -54,32 +56,123 @@ def _filter_mask(meta, params, kind):
     return in_box & ~in_ball                       # box_not_ball
 
 
-def _merge_sorted(run_d, run_i, tile_d, tile_i):
-    """Bitonic merge of two ascending [tq, kpad] lists -> ascending top-kpad."""
-    kpad = run_d.shape[1]
-    comb_d = jnp.concatenate([run_d, jnp.flip(tile_d, axis=1)], axis=1)
-    comb_i = jnp.concatenate([run_i, jnp.flip(tile_i, axis=1)], axis=1)
+def param_columns(params):
+    """Packed filter ``[4, mq]`` (box lo / hi, ball center, [r^2, ndim])
+    -> the column layout ``[mq, 8]`` the scan kernels read: columns 0-2
+    are lo, hi and center per metadata dim, columns 3 and 4 broadcast
+    r^2 and the ball's ndim, so every operand of the transposed predicate
+    is a static lane slice (no scalar extraction, no relayout)."""
+    p = jnp.asarray(params, jnp.float32)
+    mq = p.shape[1]
+    r2 = jnp.broadcast_to(p[3, 0], (mq,))
+    nd = jnp.broadcast_to(p[3, 1], (mq,))
+    z = jnp.zeros((mq,), jnp.float32)
+    return jnp.stack([p[0], p[1], p[2], r2, nd, z, z, z], axis=1)
+
+
+def _filter_mask_t(meta_t, pcol, kind):
+    """Transposed predicate over points on the lane axis: meta_t
+    ``[mq, tn]``, pcol ``[mq, 8]`` from :func:`param_columns` -> bool
+    ``[1, tn]``.  Same semantics as :func:`_filter_mask`; sublanes past
+    the metadata width pass every test (box bounds default to +/-1e30,
+    the ball's ndim stops at the center's length)."""
+    mq = meta_t.shape[0]
+    inside = (meta_t >= pcol[:, 0:1]) & (meta_t <= pcol[:, 1:2])
+    in_box = jnp.min(jnp.where(inside, 1.0, 0.0), axis=0,
+                     keepdims=True) > 0.5
+    row = jax.lax.broadcasted_iota(jnp.int32, (mq, 1), 0).astype(jnp.float32)
+    diff = meta_t - pcol[:, 2:3]
+    d2 = jnp.sum(jnp.where(row < pcol[:, 4:5], diff * diff, 0.0), axis=0,
+                 keepdims=True)
+    in_ball = d2 <= pcol[0:1, 3:4]
+    if kind == "none":
+        # padding rows carry meta = +2e30 and must still fail:
+        return meta_t[0:1, :] < _POS
+    if kind == "box":
+        return in_box
+    if kind == "ball":
+        return in_ball
+    if kind == "box_ball":
+        return in_box & in_ball
+    return in_box & ~in_ball                       # box_not_ball
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on the CPU backend
+    (tests, rehearsals) they must; on a TPU they never do — Mosaic
+    compiles them, and a kernel Mosaic refuses raises instead of falling
+    back.  The one place the mode is decided; every ``*_kernel_call``
+    resolves ``interpret=None`` through it."""
+    return jax.default_backend() == "cpu"
+
+
+def _lanes(kpad: int) -> int:
+    """Lane width of the running top-k state: the bitonic merge needs
+    ``2 * kpad`` lanes, held in whole 128-lane vregs."""
+    return max(128, 2 * kpad)
+
+
+def _tile_topk(d, base, kpad, width):
+    """``kpad`` rounds of min extraction over ``d [tq, tn]`` (one-hot
+    masking, no scatter).  The r-th smallest distance lands in lane
+    ``2 * kpad - 1 - r`` of a ``[tq, width]`` pair, i.e. lanes
+    ``[kpad, 2 * kpad)`` hold the tile's top-kpad *descending* — the
+    second half of a bitonic sequence, built in place by lane selects.
+    Ties pick the lowest column, as ``argmin`` does."""
+    tq, tn = d.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tn), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, width), 1)
+
+    def extract(r, carry):
+        d, out_d, out_i = carry
+        mn = jnp.min(d, axis=1, keepdims=True)                  # [tq, 1]
+        am = jnp.min(jnp.where(d == mn, col, tn), axis=1, keepdims=True)
+        at = lane == 2 * kpad - 1 - r
+        out_d = jnp.where(at, mn, out_d)
+        out_i = jnp.where(at, jnp.where(mn < jnp.inf, base + am, -1), out_i)
+        return jnp.where(col == am, jnp.inf, d), out_d, out_i
+
+    init = (d, jnp.full((tq, width), jnp.inf, jnp.float32),
+            jnp.full((tq, width), -1, jnp.int32))
+    _, out_d, out_i = jax.lax.fori_loop(0, kpad, extract, init)
+    return out_d, out_i
+
+
+def _merge_sorted(run_d, run_i, tile_d, tile_i, kpad):
+    """Bitonic merge of the running top-kpad (ascending, lanes
+    ``[0, kpad)`` of ``run_*``) with a tile's top-kpad (descending, lanes
+    ``[kpad, 2 * kpad)`` of ``tile_*``, see :func:`_tile_topk`) ->
+    ascending top-kpad in lanes ``[0, kpad)`` of the returned pair.
+
+    Each stage compares lane ``p`` with its partner ``p ^ stride``: a
+    lower lane reads lane ``p + stride`` and an upper lane ``p - stride``,
+    each a lane rotation (``pltpu.roll`` rotates like ``jnp.roll``), so
+    the network needs no reshape, reversal or gather (all of which Mosaic
+    refuses).  The lower lane keeps the min and swaps only on strict
+    ``>``, so ties keep their order."""
+    tq, width = run_d.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tq, width), 1)
+    d = jnp.where(lane < kpad, run_d, tile_d)
+    i = jnp.where(lane < kpad, run_i, tile_i)
     stride = kpad
     while stride >= 1:
-        tq = comb_d.shape[0]
-        nb = comb_d.shape[1] // (2 * stride)
-        d4 = comb_d.reshape(tq, nb, 2, stride)
-        i4 = comb_i.reshape(tq, nb, 2, stride)
-        a_d, b_d = d4[:, :, 0, :], d4[:, :, 1, :]
-        a_i, b_i = i4[:, :, 0, :], i4[:, :, 1, :]
-        swap = a_d > b_d
-        lo_d = jnp.where(swap, b_d, a_d)
-        hi_d = jnp.where(swap, a_d, b_d)
-        lo_i = jnp.where(swap, b_i, a_i)
-        hi_i = jnp.where(swap, a_i, b_i)
-        comb_d = jnp.stack([lo_d, hi_d], axis=2).reshape(tq, -1)
-        comb_i = jnp.stack([lo_i, hi_i], axis=2).reshape(tq, -1)
+        is_lo = (lane & stride) == 0
+        pd = jnp.where(is_lo, pltpu.roll(d, width - stride, 1),
+                       pltpu.roll(d, stride, 1))
+        pi = jnp.where(is_lo, pltpu.roll(i, width - stride, 1),
+                       pltpu.roll(i, stride, 1))
+        swap = (is_lo & (d > pd)) | (~is_lo & (pd > d))
+        d = jnp.where(swap, pd, d)
+        i = jnp.where(swap, pi, i)
         stride //= 2
-    return comb_d[:, :kpad], comb_i[:, :kpad]
+    return d, i
 
 
-def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
-                  run_d, run_i, *, metric, kind, kpad, tn, n_ctiles):
+def _fold_tile(d, ok, run_d, run_i, od_ref, oi_ref, *, kpad, tn, n_ctiles):
+    """Shared tail of the scan kernels: mask one candidate tile's
+    distances ``d [tq, tn]`` by the predicate ``ok [1, tn]``, fold its
+    top-kpad into the running list in VMEM scratch, and emit the list
+    after the last candidate tile (grid axis 1)."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -87,36 +180,9 @@ def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
         run_d[...] = jnp.full(run_d.shape, jnp.inf, jnp.float32)
         run_i[...] = jnp.full(run_i.shape, -1, jnp.int32)
 
-    q = q_ref[...]
-    x = x_ref[...]
-    ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    if metric == "l2":
-        qf = q.astype(jnp.float32)
-        xf = x.astype(jnp.float32)
-        d = (jnp.sum(qf * qf, axis=1)[:, None] - 2.0 * ip
-             + jnp.sum(xf * xf, axis=1)[None, :])
-    else:
-        d = -ip
-
-    ok = _filter_mask(s_ref[...], p_ref[...], kind)
-    d = jnp.where(ok[None, :], d, jnp.inf)
-
-    # --- tile top-k: kpad rounds of argmin + one-hot mask (no scatter) -----
-    tq = d.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tn), 1)
-    base = j * tn
-    tds, tis = [], []
-    for _ in range(kpad):
-        mn = jnp.min(d, axis=1)
-        am = jnp.argmin(d, axis=1).astype(jnp.int32)
-        tds.append(mn)
-        tis.append(jnp.where(jnp.isfinite(mn), base + am, -1))
-        d = jnp.where(col == am[:, None], jnp.inf, d)
-    tile_d = jnp.stack(tds, axis=1)                       # ascending
-    tile_i = jnp.stack(tis, axis=1)
-
-    nd, ni = _merge_sorted(run_d[...], run_i[...], tile_d, tile_i)
+    d = jnp.where(ok, d, jnp.inf)
+    tile_d, tile_i = _tile_topk(d, j * tn, kpad, run_d.shape[1])
+    nd, ni = _merge_sorted(run_d[...], run_i[...], tile_d, tile_i, kpad)
     run_d[...] = nd
     run_i[...] = ni
 
@@ -126,44 +192,70 @@ def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
         oi_ref[...] = run_i[...]
 
 
+def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
+                  run_d, run_i, *, metric, kind, kpad, tn, n_ctiles):
+    q = q_ref[...]
+    x = x_ref[...]
+    ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
+    if metric == "l2":
+        qf = q.astype(jnp.float32)
+        xf = x.astype(jnp.float32)
+        d = (jnp.sum(qf * qf, axis=1)[:, None] - 2.0 * ip
+             + jnp.sum(xf * xf, axis=1)[None, :])
+    else:
+        d = -ip
+    ok = _filter_mask_t(jnp.transpose(s_ref[...]), p_ref[...], kind)
+    _fold_tile(d, ok, run_d, run_i, od_ref, oi_ref, kpad=kpad, tn=tn,
+               n_ctiles=n_ctiles)
+
+
 @functools.partial(jax.jit, static_argnames=("metric", "kind", "kpad", "tq",
                                              "tn", "interpret"))
 def filtered_topk_kernel_call(q, x, s_pad, params, *, kind: str, kpad: int,
                               metric: str = "l2", tq: int = 64, tn: int = 256,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """Fused filtered top-k.  Pre-padded inputs:
     q [bq, d] (bq % tq == 0, d % 128 == 0), x [n, d] (n % tn == 0),
     s_pad [n, mpad] metadata padded to 128 lanes (+2e30 in padding rows so
     they fail every predicate), params [4, mpad] packed filter
     (box lo/hi, ball center, [r^2, ball_ndim]).  kpad power of two <= tn.
     Returns (dists [bq, kpad] ascending, ids [bq, kpad], -1 for misses).
+    ``interpret=None`` takes the mode from :func:`interpret_mode`.
     """
     assert kpad & (kpad - 1) == 0 and kpad <= tn
+    if interpret is None:
+        interpret = interpret_mode()
     bq, d = q.shape
     n, mpad = s_pad.shape
     grid = (bq // tq, n // tn)
+    width = _lanes(kpad)
     kern = functools.partial(_fused_kernel, metric=metric, kind=kind,
                              kpad=kpad, tn=tn, n_ctiles=grid[1])
-    return pl.pallas_call(
+    dd, ids = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((tn, mpad), lambda i, j: (j, 0)),
-            pl.BlockSpec((4, mpad), lambda i, j: (0, 0)),
+            pl.BlockSpec((mpad, 8), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, kpad), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, kpad), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, width), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, width), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bq, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((bq, kpad), jnp.int32),
+            jax.ShapeDtypeStruct((bq, width), jnp.float32),
+            jax.ShapeDtypeStruct((bq, width), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, kpad), jnp.float32),
-            pltpu.VMEM((tq, kpad), jnp.int32),
+            pltpu.VMEM((tq, width), jnp.float32),
+            pltpu.VMEM((tq, width), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, x, s_pad, params)
+    )(q, x, s_pad, param_columns(params))
+    return dd[:, :kpad], ids[:, :kpad]

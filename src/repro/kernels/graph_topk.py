@@ -37,71 +37,95 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from .filtered_topk import _filter_mask
-from .ops import encode_filter, next_pow2
+from .filtered_topk import (_filter_mask, _filter_mask_t, interpret_mode,
+                            param_columns)
+from .ops import block_mesh, encode_filter, next_pow2
 
 __all__ = ["beam_step_scores", "bucket_graph_topk"]
 
 _MPAD = 128                      # metadata lane padding (kernel layout)
 _TQ = 8                          # query-tile rows per kernel program
+_TC = 512                        # candidates per kernel program (VMEM)
+_PAD_META = 2e30                 # metadata of padding candidates
 INF = jnp.float32(np.inf)
 
 
 def _beam_step_kernel(q_ref, cx_ref, cm_ref, p_ref, od_ref, ok_ref,
                       *, metric, kind):
     """One beam step's fused score: q [tq, dp], candidates cx [tq, c, dp]
-    with metadata cm [tq, c, mpad] and packed filter p [4, mpad] ->
-    raw distances od [tq, c] + predicate mask ok [tq, c] (int32 0/1).
-    Distances are *unmasked* (routing ignores φ); the caller combines both
-    outputs for collection."""
+    with metadata cm [tq, c, mpad] and the filter in column layout p
+    [mpad, 8] (``param_columns``) -> raw distances od [tq, c] + predicate
+    mask ok [tq, c] (int32 0/1).  Distances are *unmasked* (routing
+    ignores φ); the caller combines both outputs for collection.  The
+    predicate is the scan kernels' transposed one, row by row: each query
+    row's metadata tile is transposed so its points lie on lanes."""
     q = q_ref[...]
     cx = cx_ref[...]
     tq, c, _ = cx.shape
     ip = jax.lax.dot_general(cx, q, (((2,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)  # [tq, c]
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)  # [tq, c]
     if metric == "l2":
         qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1)
         xn = jnp.sum(cx.astype(jnp.float32) ** 2, axis=2)
         d = xn - 2.0 * ip + qn[:, None]
     else:
         d = -ip
-    cm = cm_ref[...].reshape(tq * c, -1)
-    ok = _filter_mask(cm, p_ref[...], kind).reshape(tq, c)
+    pcol = p_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, (tq, c), 0)
+    ok = jnp.zeros((tq, c), jnp.int32)
+    for r in range(tq):
+        ok_r = _filter_mask_t(jnp.transpose(cm_ref[r]), pcol, kind)
+        ok = jnp.where((row == r) & ok_r, 1, ok)
     od_ref[...] = d
-    ok_ref[...] = ok.astype(jnp.int32)
+    ok_ref[...] = ok
 
 
 def beam_step_scores(q, cand_x, cand_meta, params, *, kind: str,
-                     metric: str = "l2", interpret: bool = True):
+                     metric: str = "l2", interpret: Optional[bool] = None):
     """Score one gathered candidate tile.  ``q [b, dp]`` (b % 8 == 0),
     ``cand_x [b, c, dp]``, ``cand_meta [b, c, mpad]``, ``params [4, mpad]``
     -> ``(dists [b, c] fp32 raw, ok [b, c] int32 predicate mask)``.
-    Traced — safe to call from inside a ``lax.while_loop`` body."""
+    Traced — safe to call from inside a ``lax.while_loop`` body.
+    ``interpret=None`` takes the mode from ``interpret_mode()``.
+
+    The candidate axis is tiled by ``_TC`` (padded up to a multiple when
+    longer), so VMEM stays bounded however many candidates a step scores
+    — the stitched seed set of a large bucket runs to thousands."""
     from jax.experimental import pallas as pl
+    if interpret is None:
+        interpret = interpret_mode()
     b, c, dp = cand_x.shape
     mpad = cand_meta.shape[-1]
-    grid = (b // _TQ,)
+    tc = c if c <= _TC else _TC
+    cp = -(-c // tc) * tc
+    if cp != c:                    # padding candidates: zero vector, and
+        pad = ((0, 0), (0, cp - c), (0, 0))      # metadata failing φ
+        cand_x = jnp.pad(cand_x, pad)
+        cand_meta = jnp.pad(cand_meta, pad, constant_values=_PAD_META)
     kern = functools.partial(_beam_step_kernel, metric=metric, kind=kind)
-    return pl.pallas_call(
+    d, ok = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(b // _TQ, cp // tc),
         in_specs=[
-            pl.BlockSpec((_TQ, dp), lambda i: (i, 0)),
-            pl.BlockSpec((_TQ, c, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((_TQ, c, mpad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((4, mpad), lambda i: (0, 0)),
+            pl.BlockSpec((_TQ, dp), lambda i, j: (i, 0)),
+            pl.BlockSpec((_TQ, tc, dp), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((_TQ, tc, mpad), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((mpad, 8), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((_TQ, c), lambda i: (i, 0)),
-            pl.BlockSpec((_TQ, c), lambda i: (i, 0)),
+            pl.BlockSpec((_TQ, tc), lambda i, j: (i, j)),
+            pl.BlockSpec((_TQ, tc), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
-            jax.ShapeDtypeStruct((b, c), jnp.int32),
+            jax.ShapeDtypeStruct((b, cp), jnp.float32),
+            jax.ShapeDtypeStruct((b, cp), jnp.int32),
         ],
         interpret=interpret,
-    )(q, cand_x, cand_meta, params)
+    )(q, cand_x, cand_meta, param_columns(params))
+    return d[:, :c], ok[:, :c]
 
 
 def _score_candidates_jnp(q, cx, cm, params, *, kind: str, metric: str):
@@ -111,11 +135,12 @@ def _score_candidates_jnp(q, cx, cm, params, *, kind: str, metric: str):
     On CPU the Pallas kernel only runs in interpret mode, and a traversal
     makes one kernel call *per hop* (30-50 sequential calls), so interpret
     overhead dominates end-to-end latency by orders of magnitude; this
-    twin compiles into the ``while_loop`` body as ordinary XLA.  Real
-    accelerator backends keep the fused kernel (``use_pallas``)."""
+    twin compiles into the ``while_loop`` body as ordinary XLA.  A TPU
+    keeps the fused kernel (``use_pallas``)."""
     b, c, _ = cx.shape
     ip = jax.lax.dot_general(cx, q, (((2,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
     if metric == "l2":
         qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=1)
         xn = jnp.sum(cx.astype(jnp.float32) ** 2, axis=2)
@@ -147,13 +172,22 @@ def _merge_topk(ids_a, d_a, ids_b, d_b, k):
 
 @functools.partial(jax.jit, static_argnames=(
     "k", "ef", "width", "max_iters", "kind", "metric", "m", "quantized",
-    "interpret", "use_pallas"))
+    "use_pallas", "mesh"))
 def _traverse(q, gids, nbrs, x, s, codes, st, scales, params, seeds,
               k, ef, width, max_iters, kind, metric, m, quantized,
-              interpret, use_pallas):
+              use_pallas, mesh=None):
     """Stitched best-first traversal over one bucket block.  All shapes are
     static per (bucket geometry, seed pad, k/ef/width) so repeat dispatches
-    hit the jit cache.  Returns (positions [b, k], dists [b, k], hops)."""
+    hit the jit cache.  Returns (positions [b, k], dists [b, k], hops).
+
+    Over a mesh (``mesh`` is the one the block is partitioned over) the
+    gathers stay XLA ops and the beam-step kernel runs under
+    ``shard_map`` on the replicated candidate tile — XLA cannot partition
+    a Mosaic call itself."""
+    score = functools.partial(beam_step_scores, kind=kind, metric=metric)
+    if use_pallas and mesh is not None:
+        score = jax.shard_map(score, mesh=mesh, in_specs=(P(),) * 4,
+                              out_specs=(P(), P()), check_vma=False)
     rows, cap = gids.shape
     b = q.shape[0]
     npos = rows * cap
@@ -176,8 +210,7 @@ def _traverse(q, gids, nbrs, x, s, codes, st, scales, params, seeds,
             cx = x[rv, cv]                             # [b, c, dp]
             cm = s[rv, cv]                             # [b, c, mpad]
         if use_pallas:
-            d, ok = beam_step_scores(q, cx, cm, params, kind=kind,
-                                     metric=metric, interpret=interpret)
+            d, ok = score(q, cx, cm, params)
         else:
             d, ok = _score_candidates_jnp(q, cx, cm, params, kind=kind,
                                           metric=metric)
@@ -266,8 +299,7 @@ def _traverse(q, gids, nbrs, x, s, codes, st, scales, params, seeds,
 
 def bucket_graph_topk(queries, bv, seeds, filt, k: int, *, m: int,
                       metric: str = "l2", ef: int = 64, width: int = 4,
-                      max_iters: int = 128, interpret: bool = True,
-                      use_pallas: Optional[bool] = None
+                      max_iters: int = 128
                       ) -> Optional[Tuple[np.ndarray, np.ndarray, int]]:
     """Traverse one bucket's stitched graph block.
 
@@ -278,14 +310,12 @@ def bucket_graph_topk(queries, bv, seeds, filt, k: int, *, m: int,
     distances, quantized buckets emit asymmetric-distance candidates the
     caller must rerank.  Returns ``None`` when the filter has no kernel
     encoding or the bucket has no usable graph/seeds (caller falls back to
-    the scan path).  ``use_pallas`` (default: only on real accelerator
-    backends) picks the fused kernel vs. its pure-jnp twin for hop
-    scoring — interpret-mode Pallas pays per-call overhead once per hop,
-    which dominates traversal latency on CPU."""
+    the scan path).  Hops score with the fused kernel on a TPU and with
+    its pure-jnp twin where kernels run interpreted — interpret-mode
+    Pallas pays per-call overhead once per hop, which dominates traversal
+    latency on CPU."""
     if bv.nbrs is None or len(seeds) == 0:
         return None
-    if use_pallas is None:
-        use_pallas = jax.default_backend() != "cpu"
     enc = encode_filter(filt, m)
     if enc is None:
         return None
@@ -305,6 +335,7 @@ def bucket_graph_topk(queries, bv, seeds, filt, k: int, *, m: int,
         bv.x, bv.s, bv.codes, bv.st, bv.scales,
         jnp.asarray(params), jnp.asarray(sp, jnp.int32),
         k, ef, int(width), int(max_iters), kind, metric, int(m),
-        quantized, bool(interpret), bool(use_pallas))
+        quantized, not interpret_mode(),
+        block_mesh(bv.codes if quantized else bv.x))
     return (np.asarray(g[:b], np.int64), np.asarray(dd[:b], np.float32),
             int(hops))

@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels (padding, filter encoding,
 kernel/reference dispatch).
 
-On this CPU container the kernels execute with ``interpret=True``; on a real
-TPU set ``interpret=False`` (the kernels are written with static-shape
-compare/exchange networks and 128-aligned tiles so they lower via Mosaic).
+No wrapper takes an interpret flag: each kernel call resolves its mode from
+the backend (``filtered_topk.interpret_mode``) — the Pallas interpreter on
+the CPU, Mosaic on a TPU, where a kernel that fails to compile raises.
+``tests/test_chip_compile.py`` compiles the main-path kernels for a
+described v5e topology.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.filters import (BallFilter, BoxFilter, ComposeFilter, Filter,
                             IntervalFilter)
@@ -27,7 +30,8 @@ __all__ = ["pairwise_dist", "filtered_topk", "next_pow2", "round_up",
            "sharded_filtered_topk", "sharded_filtered_topk_grouped",
            "sharded_quant_filtered_topk",
            "quant_meta_rows", "warm_sharded_shapes", "dispatch_trace_count",
-           "encode_filter", "exact_filtered_search", "PAD_META"]
+           "encode_filter", "exact_filtered_search", "place_rows",
+           "PAD_META"]
 
 _POS = 1e30
 _PAD_META = 2e30
@@ -68,7 +72,7 @@ _next_pow2 = next_pow2
 
 
 def pairwise_dist(q, x, metric: str = "l2", use_kernel: bool = True,
-                  tq: int = 128, tn: int = 512, interpret: bool = True):
+                  tq: int = 128, tn: int = 512):
     """[bq, d] x [n, d] -> [bq, n] distance matrix."""
     if not use_kernel:
         return (ref.pairwise_sq_l2(q, x) if metric == "l2"
@@ -76,8 +80,7 @@ def pairwise_dist(q, x, metric: str = "l2", use_kernel: bool = True,
     bq, n = q.shape[0], x.shape[0]
     q = _pad_to(_pad_to(jnp.asarray(q), 1, 128, 0.0), 0, tq, 0.0)
     x = _pad_to(_pad_to(jnp.asarray(x), 1, 128, 0.0), 0, tn, 0.0)
-    out = pairwise_dist_kernel_call(q, x, metric=metric, tq=tq, tn=tn,
-                                    interpret=interpret)
+    out = pairwise_dist_kernel_call(q, x, metric=metric, tq=tq, tn=tn)
     return out[:bq, :n]
 
 
@@ -172,7 +175,7 @@ def encode_filter(filt: Optional[Filter], m: int,
 
 def filtered_topk(q, x, s, filt: Optional[Filter], k: int,
                   metric: str = "l2", use_kernel: bool = True,
-                  tq: int = 64, tn: int = 256, interpret: bool = True):
+                  tq: int = 64, tn: int = 256):
     """Fused brute-force filtered top-k (exact): returns (ids [bq, k] int32
     with -1 misses, dists [bq, k] ascending)."""
     q = jnp.asarray(q, jnp.float32)
@@ -198,7 +201,7 @@ def filtered_topk(q, x, s, filt: Optional[Filter], k: int,
     sp = _pad_to(_pad_to(s, 1, 128, 0.0), 0, tn, _PAD_META)
     dd, ids = filtered_topk_kernel_call(
         qp, xp, sp, jnp.asarray(params), kind=kind, kpad=kpad, metric=metric,
-        tq=tq, tn=tn, interpret=interpret)
+        tq=tq, tn=tn)
     return ids[:bq, :k], dd[:bq, :k]
 
 
@@ -230,16 +233,44 @@ def _note_warm_sig(key: tuple) -> None:
             _WARM_SIGS.popitem(last=False)
 
 
-def _mesh_placed(arr, mesh):
-    """Pin ``arr`` with the shard-axis sharding the bucketed pack's
-    ``_place`` uses for its device blocks (mirrored here because jit
-    caches per input *sharding*: warming with unsharded zeros would
-    compile an executable a mesh-placed query never hits)."""
-    if mesh is not None and int(arr.shape[0]) % mesh.devices.size == 0:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        spec = P("shard", *([None] * (arr.ndim - 1)))
-        return jax.device_put(arr, NamedSharding(mesh, spec))
-    return arr
+def place_rows(arr, mesh):
+    """Partition a pack block's leading (row) axis over the mesh's
+    ``"shard"`` axis; ``arr`` as is without a mesh.  The bucketed pack
+    places its device blocks with it, and compile warming places its zero
+    blocks with it too (jit caches per input *sharding*: warming with
+    unsharded zeros would compile an executable a mesh-placed query never
+    hits).  A row count the mesh does not divide raises: a block is never
+    left whole on one device."""
+    if mesh is None:
+        return arr
+    nd = int(mesh.devices.size)
+    if int(arr.shape[0]) % nd:
+        raise ValueError(f"{arr.shape[0]} block rows do not divide the "
+                         f"{nd}-device shard mesh")
+    spec = P("shard", *([None] * (arr.ndim - 1)))
+    return jax.device_put(arr, NamedSharding(mesh, spec))
+
+
+def block_mesh(arr):
+    """The mesh a pack block is partitioned over (leading axis on
+    ``"shard"``), or None for a block on one device.  The dispatches key
+    on it: a Mosaic kernel cannot be partitioned by XLA, so over a mesh
+    it runs under ``shard_map`` — each device scans its resident rows."""
+    sh = getattr(arr, "sharding", None)
+    if isinstance(sh, NamedSharding) and len(sh.spec) \
+            and sh.spec[0] == "shard":
+        return sh.mesh
+    return None
+
+
+def _on_mesh(fn, mesh, in_specs, out_specs):
+    """``fn`` as is without a mesh; else mapped per device over the
+    ``"shard"`` axis (kernel calls carry no varying-axis types, hence
+    ``check_vma=False``)."""
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def warm_sharded_shapes(specs) -> int:
@@ -263,50 +294,50 @@ def warm_sharded_shapes(specs) -> int:
         rows, cap = int(spec["rows"]), int(spec["cap"])
         mesh = spec.get("mesh")
         for sig in sigs:
-            mode, kind, kpad, metric, tq, tn, interpret, bq_pad = sig[:8]
+            mode, kind, kpad, metric, tq, tn, bq_pad = sig[:7]
             if mode != spec.get("mode", "fp32"):
                 continue
             if mode == "fp32":
-                dpad = sig[8]
+                dpad = sig[7]
                 if dpad != int(spec["dpad"]):
                     continue
                 qp = jnp.zeros((bq_pad, dpad), jnp.float32)
-                x0 = _mesh_placed(jnp.zeros((rows, cap, dpad), jnp.float32),
-                                  mesh)
-                s0 = _mesh_placed(jnp.full((rows, cap, 128), _PAD_META,
-                                           jnp.float32), mesh)
+                x0 = place_rows(jnp.zeros((rows, cap, dpad), jnp.float32),
+                                mesh)
+                s0 = place_rows(jnp.full((rows, cap, 128), _PAD_META,
+                                         jnp.float32), mesh)
                 xp = _pad_to(x0, 1, tn, 0.0)
                 sp = _pad_to(s0, 1, tn, _PAD_META)
                 pj = jnp.zeros((4, 128), jnp.float32)
                 _sharded_kernel_dispatch(kind, kpad, metric, tq, tn,
-                                         interpret)(qp, xp, sp, pj)
+                                         mesh)(qp, xp, sp, pj)
             else:
-                dq, mq = sig[8], sig[9]
+                dq, mq = sig[7], sig[8]
                 if dq != int(spec["dq"]) or mq != int(spec["mq"]):
                     continue
-                sc = _mesh_placed(jnp.zeros((rows, dq), jnp.float32), mesh)
+                sc = place_rows(jnp.zeros((rows, dq), jnp.float32), mesh)
                 # reproduce the wrapper's scale-fold so the product array
                 # carries the same (propagated) sharding as a real query's
                 qs = _pad_to(jnp.zeros((bq_pad, dq), jnp.float32)[None]
                              * sc[:, None, :], 1, tq, 0.0)
-                c0 = _mesh_placed(jnp.zeros((rows, dq, cap), jnp.int8),
-                                  mesh)
-                st0 = _mesh_placed(jnp.full((rows, mq, cap), _PAD_META,
-                                            jnp.float32), mesh)
+                c0 = place_rows(jnp.zeros((rows, dq, cap), jnp.int8), mesh)
+                st0 = place_rows(jnp.full((rows, mq, cap), _PAD_META,
+                                          jnp.float32), mesh)
                 cp = _pad_to(c0, 2, tn, 0)
                 stp = _pad_to(st0, 2, tn, _PAD_META)
                 pt = jnp.zeros((4, mq), jnp.float32)
                 qn = jnp.zeros((bq_pad,), jnp.float32)
                 _sharded_quant_dispatch(kind, kpad, metric, tq, tn,
-                                        interpret)(qs, cp, stp, pt, qn)
+                                        mesh)(qs, cp, stp, pt, qn)
             warmed += 1
     return warmed
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
-                             tn: int, interpret: bool):
-    """One jitted shard-stack dispatch per (filter kind, k, tile) config.
+                             tn: int, mesh=None):
+    """One jitted shard-stack dispatch per (filter kind, k, tile, mesh)
+    config.
 
     The bucketed pack calls :func:`sharded_filtered_topk` once per
     capacity bucket, so the dispatch must not re-trace per call: this
@@ -315,20 +346,25 @@ def _sharded_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
     once and every later call (any bucket, any epoch) reuses its
     executable.
     """
-    def call(qp, xp, sp, pj):
-        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+    def scan(qp, xp, sp, pj):
         def one(x, s):
             return filtered_topk_kernel_call(qp, x, s, pj, kind=kind,
                                              kpad=kpad, metric=metric,
-                                             tq=tq, tn=tn,
-                                             interpret=interpret)
+                                             tq=tq, tn=tn)
         return jax.vmap(one)(xp, sp)
+
+    scan = _on_mesh(scan, mesh, (P(), P("shard"), P("shard"), P()),
+                    (P("shard"), P("shard")))
+
+    def call(qp, xp, sp, pj):
+        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+        return scan(qp, xp, sp, pj)
     return jax.jit(call)
 
 
 def sharded_filtered_topk(q, xs, ss, filt: Optional[Filter], k: int,
                           metric: str = "l2", use_kernel: bool = True,
-                          tq: int = 64, tn: int = 256, interpret: bool = True,
+                          tq: int = 64, tn: int = 256,
                           m: Optional[int] = None):
     """Shard-parallel fused filtered top-k: one dispatch over a stacked shard
     axis.
@@ -381,16 +417,16 @@ def sharded_filtered_topk(q, xs, ss, filt: Optional[Filter], k: int,
     xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tn, 0.0)
     sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tn, _PAD_META)
     pj = jnp.asarray(params)
-    _note_warm_sig(("fp32", kind, kpad, metric, tq, tn, interpret,
+    _note_warm_sig(("fp32", kind, kpad, metric, tq, tn,
                     int(qp.shape[0]), int(qp.shape[1])))
     dd, ids = _sharded_kernel_dispatch(kind, kpad, metric, tq, tn,
-                                       interpret)(qp, xp, sp, pj)
+                                       block_mesh(xp))(qp, xp, sp, pj)
     return ids[:, :bq, :k], dd[:, :bq, :k]
 
 
 @functools.lru_cache(maxsize=None)
 def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
-                             tn: int, interpret: bool):
+                             tn: int, mesh=None):
     """Multi-group sibling of :func:`_sharded_kernel_dispatch`: one jitted
     dispatch that vmaps the fused kernel over a *group* axis of
     ``(queries, filter params)`` pairs on top of the usual shard axis, so a
@@ -398,23 +434,27 @@ def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
     of once per distinct filter.  Groups sharing a dispatch must share the
     static config (filter kind, kpad, tiles) — the wrappers class groups by
     exactly that key."""
-    def call(qps, xp, sp, pjs):
-        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+    def scan(qps, xp, sp, pjs):
         def per_group(qp, pj):
             def one(x, s):
                 return filtered_topk_kernel_call(qp, x, s, pj, kind=kind,
                                                  kpad=kpad, metric=metric,
-                                                 tq=tq, tn=tn,
-                                                 interpret=interpret)
+                                                 tq=tq, tn=tn)
             return jax.vmap(one)(xp, sp)
         return jax.vmap(per_group)(qps, pjs)
+
+    scan = _on_mesh(scan, mesh, (P(), P("shard"), P("shard"), P()),
+                    (P(None, "shard"), P(None, "shard")))
+
+    def call(qps, xp, sp, pjs):
+        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+        return scan(qps, xp, sp, pjs)
     return jax.jit(call)
 
 
 def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
                                   use_kernel: bool = True, tq: int = 64,
-                                  tn: int = 256, interpret: bool = True,
-                                  m: Optional[int] = None):
+                                  tn: int = 256, m: Optional[int] = None):
     """Heterogeneous-filter shard-stack scan: several ``(q, filt, k)``
     request groups against ONE ``[g, n, d]`` / ``[g, n, m]`` shard stack.
 
@@ -447,8 +487,7 @@ def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
         if enc is None:
             out[i] = sharded_filtered_topk(
                 q, xs, ss, filt, int(k), metric=metric,
-                use_kernel=use_kernel, tq=tq, tn=tn, interpret=interpret,
-                m=m)
+                use_kernel=use_kernel, tq=tq, tn=tn, m=m)
             continue
         kind, params = enc
         kpad = _next_pow2(max(int(k), 8))
@@ -458,7 +497,7 @@ def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
             i, q, _, k = members[0]
             out[i] = sharded_filtered_topk(
                 q, xs, ss, groups[i][1], k, metric=metric, tq=tq, tn=tn,
-                interpret=interpret, m=m)
+                m=m)
             continue
         tnk = max(tn, kpad)
         qps, bqs = [], []
@@ -475,7 +514,7 @@ def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
         xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tnk, 0.0)
         sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tnk, _PAD_META)
         dd, ids = _grouped_kernel_dispatch(kind, kpad, metric, tq, tnk,
-                                           interpret)(qps, xp, sp, pjs)
+                                           block_mesh(xp))(qps, xp, sp, pjs)
         for gi, (i, _, _, k) in enumerate(members):
             out[i] = (ids[gi, :, :bqs[gi], :k], dd[gi, :, :bqs[gi], :k])
     return out
@@ -491,30 +530,36 @@ def quant_meta_rows(m: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _sharded_quant_dispatch(kind: str, kpad: int, metric: str, tq: int,
-                            tn: int, interpret: bool):
+                            tn: int, mesh=None):
     """Quantized sibling of :func:`_sharded_kernel_dispatch`: one jitted
-    int8 shard-stack dispatch per (filter kind, k, tile) config, vmapped
-    over the shard axis, with the per-query ``||q||^2`` term folded back
-    into the L2 distances so they are comparable with exact fp32 blocks
-    (up to quantization error)."""
-    def call(qs, cs, sts, pt, qn):
-        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+    int8 shard-stack dispatch per (filter kind, k, tile, mesh) config,
+    vmapped over the shard axis, with the per-query ``||q||^2`` term
+    folded back into the L2 distances so they are comparable with exact
+    fp32 blocks (up to quantization error)."""
+    def scan(qs, cs, sts, pt, qn):
         def one(q1, c1, s1):
             return quant_filtered_topk_kernel_call(
                 q1, c1, s1, pt, kind=kind, kpad=kpad, metric=metric,
-                tq=tq, tn=tn, interpret=interpret)
+                tq=tq, tn=tn)
         dd, ids = jax.vmap(one)(qs, cs, sts)
         if metric == "l2":
             dd = jnp.where(jnp.isfinite(dd), dd + qn[None, :, None], dd)
         return dd, ids
+
+    scan = _on_mesh(scan, mesh,
+                    (P("shard"), P("shard"), P("shard"), P(), P()),
+                    (P("shard"), P("shard")))
+
+    def call(qs, cs, sts, pt, qn):
+        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+        return scan(qs, cs, sts, pt, qn)
     return jax.jit(call)
 
 
 def sharded_quant_filtered_topk(q, codes, st, scales, filt: Optional[Filter],
                                 k: int, metric: str = "l2",
                                 use_kernel: bool = True, tq: int = 64,
-                                tn: int = 256, interpret: bool = True,
-                                m: Optional[int] = None):
+                                tn: int = 256, m: Optional[int] = None):
     """Shard-parallel fused *asymmetric-distance* filtered top-k over int8
     segment codes.
 
@@ -561,7 +606,7 @@ def sharded_quant_filtered_topk(q, codes, st, scales, filt: Optional[Filter],
         # objects, incl. polygons) over dequantized distances
         def one(qs_g, c_g, st_g):
             cf = c_g.astype(jnp.float32)
-            ip = qs_g @ cf
+            ip = jnp.matmul(qs_g, cf, precision=jax.lax.Precision.HIGHEST)
             if metric == "l2":
                 dmat = st_g[-1, :][None, :] - 2.0 * ip + qn[:, None]
             else:
@@ -583,10 +628,10 @@ def sharded_quant_filtered_topk(q, codes, st, scales, filt: Optional[Filter],
     stp = _pad_to(st, 2, tn, _PAD_META)
     qnp = _pad_to(qn, 0, tq, 0.0)
     pt = jnp.asarray(params[:, :mq])
-    _note_warm_sig(("int8", kind, kpad, metric, tq, tn, interpret,
+    _note_warm_sig(("int8", kind, kpad, metric, tq, tn,
                     int(qsp.shape[1]), dq, mq))
     dd, ids = _sharded_quant_dispatch(kind, kpad, metric, tq, tn,
-                                      interpret)(qsp, cp, stp, pt, qnp)
+                                      block_mesh(cp))(qsp, cp, stp, pt, qnp)
     return ids[:, :bq, :k], dd[:, :bq, :k]
 
 

@@ -34,93 +34,37 @@ rerank multiple); the exact fp32 rerank happens downstream
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .filtered_topk import _merge_sorted
+from .filtered_topk import (_filter_mask_t, _fold_tile, _lanes,
+                            interpret_mode, param_columns)
 
 __all__ = ["quant_filtered_topk_kernel_call"]
 
-_POS = 1e30
-
-
-def _filter_mask_t(meta_t, params_t, kind):
-    """Transposed predicate: meta_t [mq, tn], params_t [4, mq] -> bool [tn].
-
-    Same semantics as ``filtered_topk._filter_mask`` with points on the
-    lane axis; the xsq sublane (mq - 1) passes every test because the
-    packed params never constrain dims >= m (box bounds default to
-    +/-1e30, the ball's ``ndim`` mask stops at the center's length).
-    """
-    mq = meta_t.shape[0]
-    in_box = jnp.all((meta_t >= params_t[0][:, None])
-                     & (meta_t <= params_t[1][:, None]), axis=0)
-    mc = params_t[3, 1].astype(jnp.int32)
-    dim_mask = jax.lax.broadcasted_iota(jnp.int32, (mq,), 0) < mc
-    diff = meta_t - params_t[2][:, None]
-    d2 = jnp.sum(jnp.where(dim_mask[:, None], diff * diff, 0.0), axis=0)
-    in_ball = d2 <= params_t[3, 0]
-    if kind == "none":
-        # padding / dead columns carry meta = +2e30 and must still fail:
-        return meta_t[0, :] < _POS
-    if kind == "box":
-        return in_box
-    if kind == "ball":
-        return in_ball
-    if kind == "box_ball":
-        return in_box & in_ball
-    return in_box & ~in_ball                       # box_not_ball
 
 
 def _quant_fused_kernel(q_ref, c_ref, st_ref, p_ref, od_ref, oi_ref,
                         run_d, run_i, *, metric, kind, kpad, tn, n_ctiles):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        run_d[...] = jnp.full(run_d.shape, jnp.inf, jnp.float32)
-        run_i[...] = jnp.full(run_i.shape, -1, jnp.int32)
-
     qs = q_ref[...]                                 # [tq, dq] scale-folded
     c = c_ref[...].astype(jnp.float32)              # [dq, tn] int8 -> f32
     st = st_ref[...]                                # [mq, tn] meta + xsq
     ip = jax.lax.dot_general(qs, c, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+                             preferred_element_type=jnp.float32,
+                             precision=jax.lax.Precision.HIGHEST)
+    mq = st.shape[0]
     if metric == "l2":
         # partial asymmetric L2: ||q||^2 is added by the wrapper (a
         # per-query constant never reorders a query row's top-k)
-        d = st[-1, :][None, :] - 2.0 * ip
+        d = st[mq - 1:mq, :] - 2.0 * ip
     else:
         d = -ip
-
-    ok = _filter_mask_t(st, p_ref[...], kind)
-    d = jnp.where(ok[None, :], d, jnp.inf)
-
-    # --- tile top-k: kpad rounds of argmin + one-hot mask (no scatter) -----
-    tq = d.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (tq, tn), 1)
-    base = j * tn
-    tds, tis = [], []
-    for _ in range(kpad):
-        mn = jnp.min(d, axis=1)
-        am = jnp.argmin(d, axis=1).astype(jnp.int32)
-        tds.append(mn)
-        tis.append(jnp.where(jnp.isfinite(mn), base + am, -1))
-        d = jnp.where(col == am[:, None], jnp.inf, d)
-    tile_d = jnp.stack(tds, axis=1)                 # ascending
-    tile_i = jnp.stack(tis, axis=1)
-
-    nd, ni = _merge_sorted(run_d[...], run_i[...], tile_d, tile_i)
-    run_d[...] = nd
-    run_i[...] = ni
-
-    @pl.when(j == n_ctiles - 1)
-    def _emit():
-        od_ref[...] = run_d[...]
-        oi_ref[...] = run_i[...]
+    _fold_tile(d, _filter_mask_t(st, p_ref[...], kind), run_d, run_i,
+               od_ref, oi_ref, kpad=kpad, tn=tn, n_ctiles=n_ctiles)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "kind", "kpad", "tq",
@@ -128,7 +72,7 @@ def _quant_fused_kernel(q_ref, c_ref, st_ref, p_ref, od_ref, oi_ref,
 def quant_filtered_topk_kernel_call(qs, codes_t, st, params_t, *, kind: str,
                                     kpad: int, metric: str = "l2",
                                     tq: int = 64, tn: int = 256,
-                                    interpret: bool = True):
+                                    interpret: Optional[bool] = None):
     """Fused asymmetric-distance filtered top-k.  Pre-padded inputs:
     qs [bq, dq] fp32 scale-folded queries (bq % tq == 0), codes_t [dq, n]
     int8 (n % tn == 0), st [mq, n] transposed fp32 metadata whose last
@@ -136,33 +80,40 @@ def quant_filtered_topk_kernel_call(qs, codes_t, st, params_t, *, kind: str,
     they fail every predicate), params_t [4, mq] packed filter.  kpad
     power of two <= tn.  Returns (dists [bq, kpad] ascending — for L2
     *without* the ||q||^2 term, ids [bq, kpad], -1 for misses).
+    ``interpret=None`` takes the mode from ``interpret_mode()``.
     """
     assert kpad & (kpad - 1) == 0 and kpad <= tn
+    if interpret is None:
+        interpret = interpret_mode()
     bq, dq = qs.shape
     mq, n = st.shape
     grid = (bq // tq, n // tn)
+    width = _lanes(kpad)
     kern = functools.partial(_quant_fused_kernel, metric=metric, kind=kind,
                              kpad=kpad, tn=tn, n_ctiles=grid[1])
-    return pl.pallas_call(
+    dd, ids = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tq, dq), lambda i, j: (i, 0)),
             pl.BlockSpec((dq, tn), lambda i, j: (0, j)),
             pl.BlockSpec((mq, tn), lambda i, j: (0, j)),
-            pl.BlockSpec((4, mq), lambda i, j: (0, 0)),
+            pl.BlockSpec((mq, 8), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, kpad), lambda i, j: (i, 0)),
-            pl.BlockSpec((tq, kpad), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, width), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, width), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bq, kpad), jnp.float32),
-            jax.ShapeDtypeStruct((bq, kpad), jnp.int32),
+            jax.ShapeDtypeStruct((bq, width), jnp.float32),
+            jax.ShapeDtypeStruct((bq, width), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq, kpad), jnp.float32),
-            pltpu.VMEM((tq, kpad), jnp.int32),
+            pltpu.VMEM((tq, width), jnp.float32),
+            pltpu.VMEM((tq, width), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(qs, codes_t, st, params_t)
+    )(qs, codes_t, st, param_columns(params_t))
+    return dd[:, :kpad], ids[:, :kpad]

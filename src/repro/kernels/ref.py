@@ -1,10 +1,13 @@
 """Pure-jnp oracles for the Pallas kernels (the correctness references).
 
 Every kernel in this package is validated against these functions across
-shape/dtype sweeps in ``tests/test_kernels.py`` (interpret=True on CPU).
+shape/dtype sweeps in ``tests/test_kernels.py`` (interpret mode on CPU).
+Matmuls run at ``Precision.HIGHEST``: on a TPU the default is one bf16
+pass, and these distances must be the fp32 ones the kernels compute.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -18,13 +21,15 @@ def pairwise_sq_l2(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     x = jnp.asarray(x)
     qn = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1)
     xn = jnp.sum(x.astype(jnp.float32) ** 2, axis=-1)
-    ip = jnp.matmul(q, x.T, preferred_element_type=jnp.float32)
+    ip = jnp.matmul(q, x.T, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST)
     return qn[:, None] - 2.0 * ip + xn[None, :]
 
 
 def pairwise_neg_ip(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Negated inner product (so smaller = more similar), fp32 accumulation."""
-    return -jnp.matmul(q, x.T, preferred_element_type=jnp.float32)
+    return -jnp.matmul(q, x.T, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
 
 
 def filter_mask_ref(s: jnp.ndarray, kind: str, params: jnp.ndarray) -> jnp.ndarray:

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # simulated devices: never take a chip
 
 """Multi-pod dry-run (deliverable e): ``.lower().compile()`` every
 (architecture x input-shape x mesh) cell on the production meshes —
@@ -7,8 +8,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 and record memory / cost / collective-schedule evidence for §Dry-run and
 §Roofline.
 
-The two lines above MUST precede every other import (jax locks the device
-count at first init).
+The lines above MUST precede every other import (jax locks the device
+count and platform at first init).  This is a simulated-device tool: it is
+pinned to the CPU, and so is every per-cell child it starts, so it can
+never hold a TPU that another process needs.
 
 Usage:
   python -m repro.launch.dryrun --arch gemma3-1b --shape train_4k --mesh pod1
@@ -27,6 +30,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+jax.config.update("jax_platforms", "cpu")   # also when imported after jax
 
 from ..configs import ARCH_IDS, SHAPES, cell_supported, get_config
 from ..configs.shapes import ShapeSpec

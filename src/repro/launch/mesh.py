@@ -9,16 +9,9 @@ device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "mesh_compat_kwargs", "HW"]
-
-
-def mesh_compat_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on newer jax; omit it elsewhere (the
-    default is Auto on every version that has the argument)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+__all__ = ["make_production_mesh", "HW"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     import numpy as np
     return jax.sharding.Mesh(
         np.asarray(devices).reshape(shape), axes,
-        **mesh_compat_kwargs(len(axes)))
+        axis_types=(AxisType.Auto,) * len(axes))
 
 
 class HW:

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # simulated devices: never take a chip
 
 """§Perf hillclimb driver: compile a (arch x shape x mesh) cell under a named
 optimization variant and report the roofline-term deltas vs baseline.

@@ -40,6 +40,9 @@ traffic" north-star asks for, layered over
 Failure isolation mirrors ``RetrievalBatcher``: if the shared grouped
 dispatch raises, ``flush()`` falls back to per-group solo queries, each
 in its own try — one poisoned filter cannot black-hole the whole flush.
+The fallback is counted (``retrieval_group_fallback_total``) and its
+error recorded in the supervisor's health, so it never passes for a
+grouped answer.
 """
 from __future__ import annotations
 
@@ -243,7 +246,13 @@ class CubeGraphService:
                     gqs, observe_group=observe_group)
                 for chunk, res in zip(chunks, answers):
                     self._finish_chunk(out, chunk, res, t_flush)
-            except Exception:  # noqa: BLE001 — isolate per group instead
+            except Exception as exc:  # noqa: BLE001 — isolate per group
+                # never silent: a grouped dispatch that fails (a kernel
+                # that did not compile, say) is counted and its traceback
+                # lands in the supervisor's health record
+                self.metrics.counter("retrieval_group_fallback_total").inc()
+                self.store.manager.supervisor.note_error(
+                    "serving.grouped", exc)
                 for chunk, gq in zip(chunks, gqs):
                     try:
                         res = self.store.manager.query(

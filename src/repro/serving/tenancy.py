@@ -394,14 +394,20 @@ class MultiTenantStore:
             cat = json.loads((tdir / "catalog.json").read_text())
             coll = Collection(name=name, tid=int(cat["tid"]),
                               quota_points=cat["quota_points"])
+            # read each array once: indexing the NpzFile re-reads the
+            # whole array per access (quadratic, and every document would
+            # pin its own full copy)
             with np.load(tdir / "catalog.npz") as z:
-                offs = z["token_offsets"]
-                for i, g in enumerate(z["gids"].tolist()):
-                    coll.docs_by_gid[int(g)] = Document(
-                        doc_id=int(z["doc_ids"][i]),
-                        tokens=z["tokens"][offs[i]:offs[i + 1]],
-                        embedding=z["embeddings"][i],
-                        metadata=z["metadata"][i])
+                cat_arrays = {key: z[key] for key in z.files}
+            offs = cat_arrays["token_offsets"]
+            doc_ids = cat_arrays["doc_ids"].tolist()
+            emb, meta = cat_arrays["embeddings"], cat_arrays["metadata"]
+            tokens = cat_arrays["tokens"]
+            for i, g in enumerate(cat_arrays["gids"].tolist()):
+                coll.docs_by_gid[int(g)] = Document(
+                    doc_id=int(doc_ids[i]),
+                    tokens=tokens[offs[i]:offs[i + 1]],
+                    embedding=emb[i], metadata=meta[i])
             obj.collections[name] = coll
             obj.metrics.gauge(
                 f'tenant_live_points{{tenant="{name}"}}').set(coll.n_live)

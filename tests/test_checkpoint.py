@@ -69,8 +69,8 @@ def test_elastic_restore_resharded(tmp_path):
     cm = CheckpointManager(str(tmp_path))
     st = _state()
     cm.save(3, st)
-    from repro.launch.mesh import mesh_compat_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_compat_kwargs(1))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = jax.tree.map(lambda a: NamedSharding(mesh, P()), st)
     restored, _ = cm.restore(st, shardings=sh)
     assert restored["params"]["w"].sharding == NamedSharding(mesh, P())

@@ -1,0 +1,184 @@
+"""The main-path Pallas kernels compile for a TPU v5e, at real widths.
+
+Nothing runs here: each case lowers and compiles for a *described*
+``v5e:2x2`` topology (the TPU compiler is installed; no chip is attached),
+which refuses what interpret mode accepts — unsupported primitives,
+misaligned tiles, VMEM overuse, a Mosaic call XLA would have to partition.
+Real widths: d=128, metadata padded to 128 lanes, kpad 16 (k=10) and 64
+(the int8 path's 4x over-fetch), pack bucket blocks of 16 rows x 65536.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and every test worker imports this
+file.
+"""
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+D = 128
+TN = 256
+TQ = 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def mosaic(topo):
+    """Compile kernels with Mosaic although this process's backend is the
+    CPU: the kernels' backend-derived mode is steered here, in the test,
+    and the persistent compile cache stays off (entries written for a
+    described chip cannot be read back without one)."""
+    ft = importlib.import_module("repro.kernels.filtered_topk")
+    qt = importlib.import_module("repro.kernels.quant_topk")
+    gt = importlib.import_module("repro.kernels.graph_topk")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (ft, qt, gt):
+            mp.setattr(mod, "interpret_mode", lambda: False)
+        jax.clear_caches()
+        yield
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4), ("shard",),
+                axis_types=(AxisType.Auto,))
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("kind", ["box", "box_ball"])
+@pytest.mark.parametrize("kpad", [16, 64])
+def test_fused_fp32_kernel_compiles(mosaic, one_chip, kind, kpad):
+    from repro.kernels.filtered_topk import filtered_topk_kernel_call
+    f = lambda q, x, s, p: filtered_topk_kernel_call(
+        q, x, s, p, kind=kind, kpad=kpad, tq=TQ, tn=TN, interpret=False)
+    c = _compiled(f, _spec((TQ, D), jnp.float32, one_chip),
+                  _spec((65536, D), jnp.float32, one_chip),
+                  _spec((65536, 128), jnp.float32, one_chip),
+                  _spec((4, 128), jnp.float32, one_chip))
+    out = c.memory_analysis().output_size_in_bytes
+    assert out <= 2 * TQ * max(128, 2 * kpad) * 4 + 4096
+
+
+@pytest.mark.parametrize("kpad", [16, 64])
+def test_int8_kernel_compiles(mosaic, one_chip, kpad):
+    from repro.kernels.ops import quant_meta_rows
+    from repro.kernels.quant_topk import quant_filtered_topk_kernel_call
+    mq = quant_meta_rows(4)
+    f = lambda q, c, st, p: quant_filtered_topk_kernel_call(
+        q, c, st, p, kind="box_ball", kpad=kpad, tq=TQ, tn=TN,
+        interpret=False)
+    _compiled(f, _spec((TQ, D), jnp.float32, one_chip),
+              _spec((D, 65536), jnp.int8, one_chip),
+              _spec((mq, 65536), jnp.float32, one_chip),
+              _spec((4, mq), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("c", [512, 4096, 4100])
+def test_beam_step_kernel_compiles(mosaic, one_chip, c):
+    """8 query rows x c gathered candidates: one traversal hop (width 8 x
+    degree 64 = 512) and the stitched seed set of a 32-segment bucket
+    (4096, and a length the candidate tile does not divide)."""
+    from repro.kernels.graph_topk import beam_step_scores
+    f = lambda q, cx, cm, p: beam_step_scores(q, cx, cm, p, kind="box_ball",
+                                              interpret=False)
+    _compiled(f, _spec((8, D), jnp.float32, one_chip),
+              _spec((8, c, D), jnp.float32, one_chip),
+              _spec((8, c, 128), jnp.float32, one_chip),
+              _spec((4, 128), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+def test_bucket_dispatch_compiles(mosaic, one_chip, grouped):
+    """The per-bucket vmapped dispatch over a [16, 65536, ·] block, solo
+    and with 4 request groups."""
+    from repro.kernels import ops
+    blk = (_spec((16, 65536, D), jnp.float32, one_chip),
+           _spec((16, 65536, 128), jnp.float32, one_chip))
+    if grouped:
+        disp = ops._grouped_kernel_dispatch("box", 16, "l2", TQ, TN)
+        q = _spec((4, TQ, D), jnp.float32, one_chip)
+        p = _spec((4, 4, 128), jnp.float32, one_chip)
+    else:
+        disp = ops._sharded_kernel_dispatch("box", 16, "l2", TQ, TN)
+        q = _spec((TQ, D), jnp.float32, one_chip)
+        p = _spec((4, 128), jnp.float32, one_chip)
+    _compiled(disp, q, *blk, p)
+
+
+@pytest.mark.parametrize("mode", ["fp32", "int8"])
+def test_sharded_dispatch_compiles_on_mesh(mosaic, mesh4, mode):
+    """Over a 4-device "shard" mesh each device runs the kernel on its
+    resident rows: one Mosaic call, no block gathered across devices."""
+    from repro.kernels import ops
+    rows = NamedSharding(mesh4, P("shard"))
+    rep = NamedSharding(mesh4, P())
+    if mode == "fp32":
+        disp = ops._sharded_kernel_dispatch("box_ball", 16, "l2", TQ, TN,
+                                            mesh4)
+        args = (_spec((TQ, D), jnp.float32, rep),
+                _spec((16, 16384, D), jnp.float32, rows),
+                _spec((16, 16384, 128), jnp.float32, rows),
+                _spec((4, 128), jnp.float32, rep))
+    else:
+        mq = ops.quant_meta_rows(4)
+        disp = ops._sharded_quant_dispatch("box", 64, "l2", TQ, TN, mesh4)
+        args = (_spec((16, TQ, D), jnp.float32, rows),
+                _spec((16, D, 16384), jnp.int8, rows),
+                _spec((16, mq, 16384), jnp.float32, rows),
+                _spec((4, mq), jnp.float32, rep),
+                _spec((TQ,), jnp.float32, rep))
+    text = _compiled(disp, *args).as_text()
+    assert not re.search(r"all-gather|all-to-all", text)
+
+
+def test_graph_traversal_compiles_on_mesh(mosaic, mesh4):
+    """The stitched traversal over a mesh-partitioned bucket block: the
+    gathers stay XLA ops and the beam-step kernel runs under shard_map."""
+    from repro.kernels.graph_topk import _traverse
+    rows = NamedSharding(mesh4, P("shard"))
+    rep = NamedSharding(mesh4, P())
+    c = _traverse.lower(
+        _spec((8, D), jnp.float32, rep),
+        _spec((16, 16384), jnp.int32, rows),
+        _spec((16, 16384, 64), jnp.int32, rows),
+        _spec((16, 16384, D), jnp.float32, rows),
+        _spec((16, 16384, 128), jnp.float32, rows), None, None, None,
+        _spec((4, 128), jnp.float32, rep), _spec((64,), jnp.int32, rep),
+        k=10, ef=128, width=8, max_iters=256, kind="box_ball", metric="l2",
+        m=4, quantized=False, use_pallas=True, mesh=mesh4).compile()
+    assert "tpu_custom_call" in c.as_text()

@@ -20,16 +20,15 @@ import numpy as np
 import jax
 jax.devices()   # lock the 8-device backend BEFORE importing repro.launch.dryrun
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs import get_config
 from repro.configs.shapes import ShapeSpec
 from repro.launch.dryrun import build_cell, compile_cell
 from repro.distributed import hints
 
-from repro.launch.mesh import mesh_compat_kwargs
 mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4), ("data", "model"),
-            **mesh_compat_kwargs(2))
+            axis_types=(AxisType.Auto,) * 2)
 
 out = {}
 for arch in %(archs)s:

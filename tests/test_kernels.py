@@ -194,3 +194,37 @@ def test_flash_decode_bf16():
                      np.float32)
     want = np.asarray(flash_decode_ref(q, k, v, lengths), np.float32)
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("kind,c", [("box", 24), ("box_ball", 24),
+                                    ("box_ball", 600)])
+def test_beam_step_kernel_matches_jnp_twin(kind, c):
+    """The graph path's fused beam-step kernel (what a TPU runs per hop)
+    and its jnp twin (what the CPU runs) score a gathered tile alike —
+    also past one candidate tile (c > 512, padded to two)."""
+    from repro.core.filters import BallFilter, ComposeFilter
+    from repro.kernels.graph_topk import (_score_candidates_jnp,
+                                          beam_step_scores)
+    rng = np.random.default_rng(13)
+    q = rng.normal(size=(16, 128)).astype(np.float32)
+    cx = rng.normal(size=(16, c, 128)).astype(np.float32)
+    cm = np.zeros((16, c, 128), np.float32)      # live rows: 0-padded
+    cm[:, :, :3] = rng.uniform(0, 1, size=(16, c, 3))
+    cm[:, -2:, :] = 2e30                          # padding rows fail
+    f = BoxFilter(lo=jnp.asarray([0.1, 0.1, 0.2]),
+                  hi=jnp.asarray([0.9, 0.8, 0.9]))
+    if kind == "box_ball":
+        f = ComposeFilter(f, BallFilter(center=jnp.asarray([0.5, 0.5]),
+                                        radius=jnp.float32(0.3)), "and")
+    got_kind, params = encode_filter(f, 3)
+    assert got_kind == kind
+    d_k, ok_k = beam_step_scores(jnp.asarray(q), jnp.asarray(cx),
+                                 jnp.asarray(cm), jnp.asarray(params),
+                                 kind=kind)
+    d_j, ok_j = _score_candidates_jnp(jnp.asarray(q), jnp.asarray(cx),
+                                      jnp.asarray(cm), jnp.asarray(params),
+                                      kind=kind, metric="l2")
+    assert np.array_equal(np.asarray(ok_k), np.asarray(ok_j))
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_j),
+                               rtol=1e-5, atol=1e-3)
+    assert np.asarray(ok_k).any() and not np.asarray(ok_k).all()

@@ -410,9 +410,10 @@ def test_bucket_growth_is_pre_traced_off_the_query_path(quantize):
         s[:, 2] = t0 + np.linspace(0, .1, n)
         return x, s
 
+    mesh = make_shard_mesh()
     mgr = SegmentManager(16, 3, StreamConfig(
         time_dim=2, seal_max_points=1 << 30, n_shards=2, quantize=quantize,
-        index_cfg=IDX_CFG), shard_mesh=make_shard_mesh())
+        index_cfg=IDX_CFG), shard_mesh=mesh)
     x, s = batch(300, 0.0)
     mgr.ingest(x, s)
     mgr.seal()
@@ -425,7 +426,7 @@ def test_bucket_growth_is_pre_traced_off_the_query_path(quantize):
     # the dispatch the query path uses for this config (k=5 -> kpad=8)
     factory = (ops._sharded_quant_dispatch if quantize
                else ops._sharded_kernel_dispatch)
-    dispatch = factory("none", 8, "l2", 64, 256, True)
+    dispatch = factory("none", 8, "l2", 64, 256, mesh)
     compiled_before = dispatch._cache_size()
     traces_before = dispatch_trace_count()
     ids, _ = mgr.query(q, None, k=5)

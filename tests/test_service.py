@@ -102,6 +102,41 @@ def test_service_flush_bit_equals_solo_retrieve():
     assert svc.take_result(reqs[0].req_id) is None      # popped once
 
 
+def test_grouped_failure_falls_back_counted(monkeypatch):
+    """A failing grouped dispatch falls back to per-group solo queries:
+    the answers still equal solo ``retrieve``, and the fallback is
+    counted and recorded in health, never passed off as grouped."""
+    rng = np.random.default_rng(3)
+    store = _two_tenant_store(rng)
+    svc = CubeGraphService(store)
+    filters = _filters()
+    reqs = [ServeRequest(req_id=rid, tenant=("a", "b")[rid % 2],
+                         query_emb=rng.standard_normal(D)
+                         .astype(np.float32),
+                         filt=filters[rid % 3], k=5)
+            for rid in range(6)]
+
+    def boom(*a, **kw):
+        raise RuntimeError("grouped dispatch failed")
+
+    monkeypatch.setattr(store.manager, "query_grouped", boom)
+    counter = store.metrics.counter("retrieval_group_fallback_total")
+    assert counter.value == 0
+    for r in reqs:
+        assert svc.submit(r) is None
+    answers = svc.flush()
+    assert counter.value == 1
+    health = store.manager.supervisor.health()["serving.grouped"]
+    assert health["errors"] == 1
+    assert "grouped dispatch failed" in health["last_error"]
+    for r in reqs:
+        sr = answers[r.req_id]
+        solo = store.retrieve(r.tenant, r.query_emb, r.filt, k=r.k)
+        assert np.array_equal(sr.gids, solo.gids[0])
+        assert np.array_equal(sr.dists, solo.dists[0])
+        assert not sr.degraded
+
+
 def test_document_store_retrieve_grouped_parity():
     """``DocumentStore.retrieve_grouped`` over heterogeneous (filter, k)
     requests returns per-request rows identical to solo ``retrieve``."""

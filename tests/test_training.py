@@ -101,8 +101,8 @@ def test_compressed_psum_shardmap():
     from jax.sharding import Mesh, PartitionSpec as P
     from jax.experimental.shard_map import shard_map
     from repro.training.compression import compressed_psum
-    from repro.launch.mesh import mesh_compat_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_compat_kwargs(1))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(32,)),
                           jnp.float32)}
     e = init_error_state(g)
