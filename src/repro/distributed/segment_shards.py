@@ -1256,6 +1256,14 @@ def pack_search_blocks_grouped(view: PackView, groups,
     dispatch per group — the per-tenant ``BucketStats`` hook.  Returns one
     candidate-block list per group (a dropped group keeps the blocks
     gathered before its deadline expired).
+
+    ``trace`` opens one ``bucket_dispatch_grouped`` span per dispatched
+    bucket holding the host's steps: the kernel wrapper's
+    ``group_stack`` / ``kernel_launch`` / ``group_split``, one
+    ``shard_merge`` per group (its merge program's dispatch),
+    ``device_wait`` (the bucket's one wait for the device, which the
+    untraced path makes too) and one ``readback`` per group (its
+    device-to-host copies and candidate count).
     """
     trace = NULL_TRACE if trace is None else trace
     groups = [(np.atleast_2d(np.asarray(q, np.float32)), f, int(k),
@@ -1302,32 +1310,36 @@ def pack_search_blocks_grouped(view: PackView, groups,
             sub = [(groups[gi][0], groups[gi][1], min(groups[gi][2], bv.cap))
                    for gi in live]
             results = sharded_filtered_topk_grouped(sub, bv.x, bv.s,
-                                                    metric=metric, m=view.m)
+                                                    metric=metric, m=view.m,
+                                                    trace=trace)
             merged = []
             for (ids, dd), gi in zip(results, live):
-                kk = min(groups[gi][2], bv.cap)
-                k_out = min(groups[gi][2], rows * kk)
-                merged.append(_merge_shard_topk(ids, dd, bv.gids,
-                                                jnp.asarray(actives[gi]),
-                                                k_out))
-            block_ready(merged[-1])
-        cache_hit = (dispatch_trace_count() == traces0) if want_obs \
-            else False
-        n_cand_total = 0
-        for (out_g, out_d), gi in zip(merged, live):
-            out_g = np.asarray(out_g, np.int64)
-            out_d = np.asarray(out_d, np.float32)
-            blocks[gi].append((out_g, out_d))
-            if want_obs:
-                n_cand = int((out_g >= 0).sum())
-                n_cand_total += n_cand
-                if observe_group is not None:
-                    observe_group(
-                        gi, bv.cap, rows=rows,
-                        active_rows=int(actives[gi].sum()),
-                        candidates=n_cand,
-                        candidate_slots=out_g.shape[0] * out_g.shape[1],
-                        cache_hit=cache_hit)
+                with trace.span("shard_merge", group=gi):
+                    kk = min(groups[gi][2], bv.cap)
+                    k_out = min(groups[gi][2], rows * kk)
+                    merged.append(_merge_shard_topk(
+                        ids, dd, bv.gids, jnp.asarray(actives[gi]), k_out))
+            with trace.span("device_wait"):
+                block_ready(merged[-1])
+            cache_hit = (dispatch_trace_count() == traces0) if want_obs \
+                else False
+            n_cand_total = 0
+            for (out_g, out_d), gi in zip(merged, live):
+                with trace.span("readback", group=gi):
+                    out_g = np.asarray(out_g, np.int64)
+                    out_d = np.asarray(out_d, np.float32)
+                    blocks[gi].append((out_g, out_d))
+                    if want_obs:
+                        n_cand = int((out_g >= 0).sum())
+                        n_cand_total += n_cand
+                        if observe_group is not None:
+                            observe_group(
+                                gi, bv.cap, rows=rows,
+                                active_rows=int(actives[gi].sum()),
+                                candidates=n_cand,
+                                candidate_slots=(out_g.shape[0]
+                                                 * out_g.shape[1]),
+                                cache_hit=cache_hit)
         if want_obs:
             sp.annotate(candidates=n_cand_total, cache_hit=cache_hit)
             if observe is not None:

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.filters import (BallFilter, BoxFilter, ComposeFilter, Filter,
                             IntervalFilter)
+from ..obs.trace import NULL_TRACE
 from . import ref
 from .distance import pairwise_dist_kernel_call
 from .filtered_topk import filtered_topk_kernel_call
@@ -454,7 +455,8 @@ def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
 
 def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
                                   use_kernel: bool = True, tq: int = 64,
-                                  tn: int = 256, m: Optional[int] = None):
+                                  tn: int = 256, m: Optional[int] = None,
+                                  trace=None):
     """Heterogeneous-filter shard-stack scan: several ``(q, filt, k)``
     request groups against ONE ``[g, n, d]`` / ``[g, n, m]`` shard stack.
 
@@ -475,48 +477,64 @@ def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
     sibling groups cannot perturb a row's distances), and a class shares
     the per-group static config with the solo dispatch, so the vmapped
     call runs the identical computation per group.
+
+    ``trace`` (a ``repro.obs.trace.QueryTrace``, default off) times the
+    host's steps: ``group_stack`` (filter encoding; each class's padding
+    and stacking), ``kernel_launch`` (the jitted call, which returns once
+    the work is enqueued; a solo dispatch with ``solo=True``) and one
+    ``group_split`` per stacked group (its eager result slices).
     """
+    trace = NULL_TRACE if trace is None else trace
     groups = list(groups)
     xs = jnp.asarray(xs, jnp.float32)
     ss = jnp.asarray(ss, jnp.float32)
     m = ss.shape[2] if m is None else int(m)
     out: list = [None] * len(groups)
+    solo: list = []
     classes: "OrderedDict[tuple, list]" = OrderedDict()
-    for i, (q, filt, k) in enumerate(groups):
-        enc = encode_filter(filt, m) if use_kernel else None
-        if enc is None:
+    with trace.span("group_stack", groups=len(groups)):
+        for i, (q, filt, k) in enumerate(groups):
+            enc = encode_filter(filt, m) if use_kernel else None
+            if enc is None:
+                solo.append(i)
+                continue
+            kind, params = enc
+            kpad = _next_pow2(max(int(k), 8))
+            classes.setdefault((kind, kpad), []).append(
+                (i, q, params, int(k)))
+    solo += [members[0][0] for members in classes.values()
+             if len(members) == 1]
+    for i in solo:
+        q, filt, k = groups[i]
+        with trace.span("kernel_launch", groups=1, solo=True):
             out[i] = sharded_filtered_topk(
                 q, xs, ss, filt, int(k), metric=metric,
                 use_kernel=use_kernel, tq=tq, tn=tn, m=m)
-            continue
-        kind, params = enc
-        kpad = _next_pow2(max(int(k), 8))
-        classes.setdefault((kind, kpad), []).append((i, q, params, int(k)))
     for (kind, kpad), members in classes.items():
         if len(members) == 1:
-            i, q, _, k = members[0]
-            out[i] = sharded_filtered_topk(
-                q, xs, ss, groups[i][1], k, metric=metric, tq=tq, tn=tn,
-                m=m)
             continue
-        tnk = max(tn, kpad)
-        qps, bqs = [], []
-        for _, q, _, _ in members:
-            q = jnp.asarray(q, jnp.float32)
-            bqs.append(q.shape[0])
-            qps.append(_pad_to(_pad_to(q, 1, 128, 0.0), 0, tq, 0.0))
-        bq_pad = max(qp.shape[0] for qp in qps)
-        qps = jnp.stack([qp if qp.shape[0] == bq_pad
-                         else jnp.pad(qp, ((0, bq_pad - qp.shape[0]),
-                                           (0, 0)))
-                         for qp in qps])
-        pjs = jnp.stack([jnp.asarray(p) for _, _, p, _ in members])
-        xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tnk, 0.0)
-        sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tnk, _PAD_META)
-        dd, ids = _grouped_kernel_dispatch(kind, kpad, metric, tq, tnk,
-                                           block_mesh(xp))(qps, xp, sp, pjs)
+        with trace.span("group_stack", groups=len(members)):
+            tnk = max(tn, kpad)
+            qps, bqs = [], []
+            for _, q, _, _ in members:
+                q = jnp.asarray(q, jnp.float32)
+                bqs.append(q.shape[0])
+                qps.append(_pad_to(_pad_to(q, 1, 128, 0.0), 0, tq, 0.0))
+            bq_pad = max(qp.shape[0] for qp in qps)
+            qps = jnp.stack([qp if qp.shape[0] == bq_pad
+                             else jnp.pad(qp, ((0, bq_pad - qp.shape[0]),
+                                               (0, 0)))
+                             for qp in qps])
+            pjs = jnp.stack([jnp.asarray(p) for _, _, p, _ in members])
+            xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tnk, 0.0)
+            sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tnk, _PAD_META)
+        with trace.span("kernel_launch", groups=len(members)):
+            dd, ids = _grouped_kernel_dispatch(kind, kpad, metric, tq, tnk,
+                                               block_mesh(xp))(qps, xp, sp,
+                                                               pjs)
         for gi, (i, _, _, k) in enumerate(members):
-            out[i] = (ids[gi, :, :bqs[gi], :k], dd[gi, :, :bqs[gi], :k])
+            with trace.span("group_split"):
+                out[i] = (ids[gi, :, :bqs[gi], :k], dd[gi, :, :bqs[gi], :k])
     return out
 
 

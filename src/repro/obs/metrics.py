@@ -31,6 +31,8 @@ import re
 import threading
 from typing import Dict, Optional
 
+from .trace import compile_counts
+
 __all__ = ["NULL_METRIC", "NULL_REGISTRY", "SUBBUCKETS", "BucketStats",
            "Counter", "Gauge", "Histogram", "MetricsRegistry", "StreamObs",
            "json_sanitize", "prometheus_text"]
@@ -340,10 +342,20 @@ class StreamObs:
         self.bucket_stats = BucketStats() if enabled else None
 
     def snapshot(self) -> dict:
-        """JSON-safe ``{enabled, metrics, buckets}`` export."""
+        """JSON-safe ``{enabled, metrics, buckets}`` export.  An enabled
+        snapshot's counters include the process-wide
+        ``xla_compiles_total`` and ``xla_cache_loads_total``
+        (:func:`repro.obs.trace.compile_counts`)."""
+        metrics = self.registry.snapshot()
+        if self.enabled:
+            counts = compile_counts()
+            metrics["counters"] = dict(sorted({
+                **metrics["counters"],
+                "xla_compiles_total": counts["compiles"],
+                "xla_cache_loads_total": counts["cache_loads"]}.items()))
         return {
             "enabled": self.enabled,
-            "metrics": self.registry.snapshot(),
+            "metrics": metrics,
             "buckets": (self.bucket_stats.snapshot()
                         if self.bucket_stats is not None else {}),
         }

@@ -1,37 +1,109 @@
-"""Structured per-query tracing: nested spans that block on device work.
+"""Structured tracing: nested host spans on the device trace's clock.
 
 A :class:`QueryTrace` is a tree of :class:`Span` context managers opened
-along the query path (delta scan, per-bucket dispatch, rerank, merge).
-Two rules make the numbers honest under JAX's async dispatch:
+along the query path (delta scan, per-bucket dispatch, rerank, merge)
+and, for a served flush, along ``CubeGraphService.flush``.  Three rules
+make the numbers honest under JAX's async dispatch:
 
-* every span body that launches device work calls :func:`block_ready` on
-  its results **before** the span closes, so the recorded duration covers
-  the device computation, not just the Python-side enqueue;
-* every span wraps ``jax.profiler.TraceAnnotation``, so the same span
-  names line up with XLA's own timeline in a captured profile.
+* a span times the host: it closes when the Python work inside it
+  returns, and device work it enqueued may still be running.  Device
+  time comes from the device trace, on the same clock;
+* every span wraps ``jax.profiler.TraceAnnotation`` named
+  ``cubegraph.<span name>`` on the thread that does the work, so a
+  captured profile holds the program's spans beside the device's
+  operations and a trace reduction tells them from JAX's own host events
+  (``Span.name`` and :meth:`QueryTrace.to_dict` keep the bare names);
+* no span adds a ``block_until_ready`` or a host copy that the untraced
+  path does not make.  Where the path itself waits (:func:`block_ready`
+  on the solo path, ``device_wait`` on the grouped path), the span
+  around the wait times it.
+
+Compiles are counted in the program: one process-wide
+``jax.monitoring`` listener counts backend compiles and
+persistent-cache loads (:func:`compile_counts`) and adds each to the
+innermost open span of the compiling thread, as attributes ``compiles``
+and ``cache_loads``; a finished trace's root carries the totals of its
+tree.
 
 The disabled path is a set of shared singletons (:data:`NULL_TRACE` /
 its no-op span): opening a span on a disabled trace allocates nothing
-and touches no clocks, which is what keeps tracing per-query opt-in
-(``SegmentManager.query(..., return_trace=True)``) rather than a
-standing tax.
+and touches no clocks, which is what keeps tracing opt-in
+(``SegmentManager.query(..., return_trace=True)``, a
+``CubeGraphService`` given a :class:`TraceLog`) rather than a standing
+tax.
 """
 from __future__ import annotations
 
+import threading
 import time
-from typing import List, Optional
+from collections import deque
+from typing import Dict, Iterator, List, Optional
 
 import jax
 
-__all__ = ["NULL_TRACE", "QueryTrace", "Span", "block_ready"]
+__all__ = ["ANNOTATION_PREFIX", "NULL_TRACE", "QueryTrace", "Span",
+           "TraceLog", "block_ready", "compile_counts"]
+
+ANNOTATION_PREFIX = "cubegraph."
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_hits"
+COUNT_KEYS = ("compiles", "cache_loads")
+
+_LOCAL = threading.local()        # per thread: open spans, pending load
+_COUNTS = dict.fromkeys(COUNT_KEYS, 0)
+_COUNTS_LOCK = threading.Lock()
+
+
+def _open_spans() -> List["Span"]:
+    spans = getattr(_LOCAL, "spans", None)
+    if spans is None:
+        spans = _LOCAL.spans = []
+    return spans
+
+
+def _note(key: str) -> None:
+    with _COUNTS_LOCK:
+        _COUNTS[key] += 1
+    spans = getattr(_LOCAL, "spans", None)
+    if spans:
+        attrs = spans[-1].attrs
+        attrs[key] = attrs.get(key, 0) + 1
+
+
+def _on_event(name: str, **_kw) -> None:
+    if name == CACHE_LOAD_EVENT:
+        # JAX times a persistent-cache load as a backend compile too: the
+        # duration event that follows on this thread is this load
+        _LOCAL.loading = True
+        _note("cache_loads")
+
+
+def _on_duration(name: str, _secs: float, **_kw) -> None:
+    if name == COMPILE_EVENT:
+        if getattr(_LOCAL, "loading", False):
+            _LOCAL.loading = False
+        else:
+            _note("compiles")
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_counts() -> Dict[str, int]:
+    """Process-wide ``{"compiles", "cache_loads"}`` since import: XLA
+    backend compiles, and executables loaded from the persistent
+    compilation cache instead."""
+    with _COUNTS_LOCK:
+        return dict(_COUNTS)
 
 
 def block_ready(value):
     """``jax.block_until_ready`` that tolerates numpy/None pytrees.
 
-    The query path's timer-stop pattern: call on every dispatch result
-    before reading a clock, so measured time includes device execution.
-    Returns ``value`` unchanged.
+    Returns ``value`` unchanged once its device work has finished.  Only
+    call it where the path needs the result on the host anyway: a span
+    around it then times the wait, and tracing adds no sync.
     """
     if value is None:
         return value
@@ -57,20 +129,31 @@ class Span:
         self.attrs.update(attrs)
 
     def start(self) -> "Span":
-        """Open the XLA trace annotation and start the wall clock."""
-        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        """Open the ``cubegraph.<name>`` profiler annotation, make this the
+        thread's innermost open span, and start the wall clock."""
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name)
         self._annotation.__enter__()
+        _open_spans().append(self)
         self._t0 = time.perf_counter()
         return self
 
     def stop(self) -> None:
-        """Stop the wall clock and close the XLA annotation.  Callers must
-        :func:`block_ready` device results first — that ordering is the
-        whole point of the tracer."""
+        """Stop the wall clock and close the profiler annotation (on the
+        thread that started the span)."""
         self.duration_ms = (time.perf_counter() - self._t0) * 1e3
+        spans = _open_spans()
+        if self in spans:
+            spans.remove(self)
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
             self._annotation = None
+
+    def walk(self) -> Iterator["Span"]:
+        """This span and every span below it, depth first."""
+        yield self
+        for c in self.children:
+            yield from c.walk()
 
     def to_dict(self) -> dict:
         """JSON-safe ``{name, ms, attrs?, spans?}`` subtree."""
@@ -104,10 +187,12 @@ class _SpanCtx:
 class QueryTrace:
     """Span tree for one query; the root span times the whole call.
 
-    Created by ``SegmentManager.query(..., return_trace=True)`` (or
-    directly) and threaded through ``streaming.query.query_segments`` and
-    ``distributed.segment_shards.pack_search*``.  :meth:`finish` stops
-    the root; :meth:`to_dict` exports the tree.
+    Created by ``SegmentManager.query(..., return_trace=True)``, by a
+    ``CubeGraphService`` flush with a :class:`TraceLog` set (or
+    directly) and threaded through ``streaming.query.query_segments*``
+    and ``distributed.segment_shards.pack_search*``.  :meth:`finish`
+    stops the root; :meth:`to_dict` exports the tree.  A trace belongs to
+    the thread that created it.
     """
 
     enabled = True
@@ -124,9 +209,14 @@ class QueryTrace:
         return _SpanCtx(self, sp)
 
     def finish(self) -> "QueryTrace":
-        """Stop the root span (idempotent enough for one query's life)."""
-        if self.root._annotation is not None:
-            self.root.stop()
+        """Stop the root span and give it the tree's ``compiles`` and
+        ``cache_loads`` totals (idempotent)."""
+        root = self.root
+        if root._annotation is not None:
+            root.stop()
+            totals = {key: sum(sp.attrs.get(key, 0) for sp in root.walk())
+                      for key in COUNT_KEYS}
+            root.attrs.update(totals)
         return self
 
     @property
@@ -137,6 +227,35 @@ class QueryTrace:
     def to_dict(self) -> dict:
         """JSON-safe span tree (root node)."""
         return self.root.to_dict()
+
+
+class TraceLog:
+    """Bounded sink of finished traces: keeps the newest ``maxlen``.
+
+    ``CubeGraphService(trace_log=...)`` adds one ``serve.flush`` trace
+    per flush; set the attribute to ``None`` to stop tracing.
+    """
+
+    def __init__(self, maxlen: int = 4096):
+        self._traces: deque = deque(maxlen=int(maxlen))
+        self.added = 0
+
+    def add(self, trace: QueryTrace) -> None:
+        """Keep one finished trace (dropping the oldest when full)."""
+        self._traces.append(trace)
+        self.added += 1
+
+    @property
+    def dropped(self) -> int:
+        """Traces added but no longer kept."""
+        return self.added - len(self._traces)
+
+    def traces(self) -> List[QueryTrace]:
+        """The kept traces, oldest first."""
+        return list(self._traces)
+
+    def __len__(self) -> int:
+        return len(self._traces)
 
 
 class _NullSpan:

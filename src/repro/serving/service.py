@@ -55,6 +55,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..core import Filter
+from ..obs.trace import NULL_TRACE, QueryTrace, TraceLog
 from ..streaming import GroupQuery
 from .batching import RetrievalFailure, _filter_key
 from .rag import Document
@@ -135,14 +136,18 @@ class CubeGraphService:
     ``req_id`` (popped by :meth:`take_result`) so async-loop clients can
     poll.  ``maintenance_every > 0`` triggers one substrate lifecycle
     tick (async compaction) every that-many flushes, exactly like
-    ``RetrievalBatcher``.
+    ``RetrievalBatcher``.  ``trace_log`` (settable at any time, ``None``
+    by default) is the sink of per-flush traces: set, every flush that
+    drains requests records a ``serve.flush`` span tree into it.
     """
 
     def __init__(self, store: MultiTenantStore,
                  admission: Optional[AdmissionController] = None,
                  ef: int = 64, max_batch: int = 64,
-                 maintenance_every: int = 0):
+                 maintenance_every: int = 0,
+                 trace_log: Optional[TraceLog] = None):
         self.store = store
+        self.trace_log = trace_log
         self.admission = admission or AdmissionController()
         self.ef = int(ef)
         self.max_batch = int(max_batch)
@@ -207,12 +212,36 @@ class CubeGraphService:
         then shares per-bucket device reads in a single
         ``query_grouped`` pass.  Returns (and retains in
         :attr:`results`) ``{req_id: ServeResult | RetrievalFailure}``.
+
+        With :attr:`trace_log` set, a flush that drained requests adds
+        one finished ``serve.flush`` :class:`~repro.obs.trace.QueryTrace`
+        to it (span tree in ``docs/observability.md``).
         """
         with self._lock:
             drained: List[ServeRequest] = list(self.queue)
             self.queue.clear()
         out: Dict[int, object] = {}
         if drained:
+            sink = self.trace_log
+            trace = NULL_TRACE if sink is None else QueryTrace("serve.flush")
+            try:
+                self._serve(drained, out, trace)
+            finally:
+                if sink is not None:
+                    sink.add(trace.finish())
+        with self._lock:
+            self.results.update(out)
+        self._flushes += 1
+        if (self.maintenance_every > 0
+                and self._flushes % self.maintenance_every == 0):
+            self.store.maintenance(async_compaction=True)
+        return out
+
+    def _serve(self, drained: List[ServeRequest], out: Dict[int, object],
+               trace) -> None:
+        """Group ``drained``, answer the groups in one ``query_grouped``
+        pass, and fill ``out`` with each request's result."""
+        with trace.span("serve.group"):
             grouped: Dict[object, List[ServeRequest]] = {}
             for r in drained:
                 grouped.setdefault(
@@ -225,11 +254,15 @@ class CubeGraphService:
             t_flush = time.perf_counter()
             wait_hist = self.metrics.histogram("retrieval_queue_wait_ms")
             occ_hist = self.metrics.histogram("retrieval_batch_occupancy")
+            wait_ms, n_waits = 0.0, 0
             for chunk in chunks:
                 occ_hist.observe(len(chunk) / self.max_batch)
                 for r in chunk:
                     if r.enqueued_at:
-                        wait_hist.observe((t_flush - r.enqueued_at) * 1e3)
+                        w = (t_flush - r.enqueued_at) * 1e3
+                        wait_hist.observe(w)
+                        wait_ms += w
+                        n_waits += 1
             gqs = [GroupQuery(
                 np.stack([r.query_emb for r in chunk]).astype(np.float32),
                 self.store.scoped_filter(chunk[0].tenant, chunk[0].filt),
@@ -237,69 +270,67 @@ class CubeGraphService:
                 deadline_ms=chunk[0].deadline_ms) for chunk in chunks]
             stats_of = [self.store.collections[c[0].tenant].bucket_stats
                         for c in chunks]
+        if trace.enabled:
+            trace.root.annotate(
+                requests=len(drained), groups=len(chunks),
+                queue_wait_ms=wait_ms / n_waits if n_waits else 0.0)
 
-            def observe_group(gi, cap, **kw):
-                stats_of[gi].observe(cap, **kw)
+        def observe_group(gi, cap, **kw):
+            stats_of[gi].observe(cap, **kw)
 
-            try:
+        try:
+            with trace.span("serve.query_grouped"):
                 answers = self.store.manager.query_grouped(
-                    gqs, observe_group=observe_group)
-                for chunk, res in zip(chunks, answers):
-                    self._finish_chunk(out, chunk, res, t_flush)
-            except Exception as exc:  # noqa: BLE001 — isolate per group
-                # never silent: a grouped dispatch that fails (a kernel
-                # that did not compile, say) is counted and its traceback
-                # lands in the supervisor's health record
-                self.metrics.counter("retrieval_group_fallback_total").inc()
-                self.store.manager.supervisor.note_error(
-                    "serving.grouped", exc)
-                for chunk, gq in zip(chunks, gqs):
-                    try:
-                        res = self.store.manager.query(
-                            gq.queries, gq.filt, k=gq.k, ef=gq.ef,
-                            deadline_ms=gq.deadline_ms)
-                        self._finish_chunk(out, chunk, res, t_flush)
-                    except Exception as exc:  # noqa: BLE001
-                        self.metrics.counter(
-                            "retrieval_failed_total").inc(len(chunk))
-                        for r in chunk:
-                            out[r.req_id] = RetrievalFailure(
-                                r.req_id,
-                                f"{type(exc).__name__}: {exc}")
-        with self._lock:
-            self.results.update(out)
-        self._flushes += 1
-        if (self.maintenance_every > 0
-                and self._flushes % self.maintenance_every == 0):
-            self.store.maintenance(async_compaction=True)
-        return out
+                    gqs, trace=trace, observe_group=observe_group)
+            for chunk, res in zip(chunks, answers):
+                self._finish_chunk(out, chunk, res, t_flush, trace)
+        except Exception as exc:  # noqa: BLE001 — isolate per group
+            # never silent: a grouped dispatch that fails (a kernel
+            # that did not compile, say) is counted and its traceback
+            # lands in the supervisor's health record
+            self.metrics.counter("retrieval_group_fallback_total").inc()
+            self.store.manager.supervisor.note_error(
+                "serving.grouped", exc)
+            for chunk, gq in zip(chunks, gqs):
+                try:
+                    res = self.store.manager.query(
+                        gq.queries, gq.filt, k=gq.k, ef=gq.ef,
+                        deadline_ms=gq.deadline_ms)
+                    self._finish_chunk(out, chunk, res, t_flush, trace)
+                except Exception as exc:  # noqa: BLE001
+                    self.metrics.counter(
+                        "retrieval_failed_total").inc(len(chunk))
+                    for r in chunk:
+                        out[r.req_id] = RetrievalFailure(
+                            r.req_id,
+                            f"{type(exc).__name__}: {exc}")
 
     def _finish_chunk(self, out: Dict[int, object],
-                      chunk: List[ServeRequest], res, t_flush: float
-                      ) -> None:
+                      chunk: List[ServeRequest], res, t_flush: float,
+                      trace) -> None:
         """Split one answered group back into per-request results."""
-        tenant = chunk[0].tenant
-        gids = np.asarray(res[0], np.int64)
-        dists = np.asarray(res[1], np.float32)
-        degraded = bool(getattr(res, "degraded", False))
-        reasons = dict(getattr(res, "reasons", {}) or {})
-        docs = self.store.materialize(tenant, gids)
-        now = time.perf_counter()
-        lat_hist = self.metrics.histogram(
-            f'tenant_request_ms{{tenant="{tenant}"}}')
-        self.metrics.counter(
-            f'tenant_requests_total{{tenant="{tenant}"}}').inc(len(chunk))
-        if degraded:
+        with trace.span("serve.finish", requests=len(chunk)):
+            tenant = chunk[0].tenant
+            gids = np.asarray(res[0], np.int64)
+            dists = np.asarray(res[1], np.float32)
+            degraded = bool(getattr(res, "degraded", False))
+            reasons = dict(getattr(res, "reasons", {}) or {})
+            with trace.span("materialize"):
+                docs = self.store.materialize(tenant, gids)
+            now = time.perf_counter()
             self.metrics.counter(
-                f'tenant_degraded_total{{tenant="{tenant}"}}').inc(
+                f'tenant_requests_total{{tenant="{tenant}"}}').inc(
                     len(chunk))
-        for i, r in enumerate(chunk):
-            lat = (now - (r.enqueued_at or t_flush)) * 1e3
-            lat_hist.observe(lat)
-            out[r.req_id] = ServeResult(
-                req_id=r.req_id, tenant=tenant, docs=docs[i],
-                gids=gids[i], dists=dists[i], degraded=degraded,
-                reasons=reasons, latency_ms=lat)
+            if degraded:
+                self.metrics.counter(
+                    f'tenant_degraded_total{{tenant="{tenant}"}}').inc(
+                        len(chunk))
+            for i, r in enumerate(chunk):
+                out[r.req_id] = ServeResult(
+                    req_id=r.req_id, tenant=tenant, docs=docs[i],
+                    gids=gids[i], dists=dists[i], degraded=degraded,
+                    reasons=reasons,
+                    latency_ms=(now - (r.enqueued_at or t_flush)) * 1e3)
 
     # -- async loop ----------------------------------------------------
 

@@ -1121,9 +1121,9 @@ class SegmentManager:
         ``return_trace`` appends a finished
         :class:`~repro.obs.trace.QueryTrace` to the result tuple — a span
         tree decomposing this call's latency (delta scan, per-bucket
-        dispatch, rerank, merge) with every timer stopped only after
-        ``jax.block_until_ready``.  Tracing never changes results (see
-        ``tests/test_obs.py``).
+        dispatch, rerank, merge); each solo-path span stops after the
+        ``block_until_ready`` the path makes anyway.  Tracing never
+        changes results (see ``tests/test_obs.py``).
 
         ``deadline_ms`` (forwarded via ``**kw``, default
         ``StreamConfig.query_deadline_ms``) bounds this call's time
@@ -1138,9 +1138,13 @@ class SegmentManager:
         from ..obs.trace import QueryTrace
         from .resilience import QueryResult
         trace = QueryTrace("query")
-        out = query_segments(self, queries, filt, k=k, ef=ef,
-                             return_stats=return_stats, trace=trace, **kw)
-        res = out + (trace.finish(),)
+        try:
+            out = query_segments(self, queries, filt, k=k, ef=ef,
+                                 return_stats=return_stats, trace=trace,
+                                 **kw)
+        finally:
+            trace.finish()
+        res = out + (trace,)
         if isinstance(out, QueryResult):     # keep degraded metadata:
             res = QueryResult(res, degraded=out.degraded,   # tuple concat
                               reasons=out.reasons)          # strips it

@@ -564,9 +564,12 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
     ``observe_group(group_idx, cap, rows=, active_rows=, candidates=,
     candidate_slots=, cache_hit=)`` attributes each shared bucket
     dispatch back to the groups that rode it — the hook the serving tier
-    uses for per-tenant ``BucketStats``.  Returns one
-    ``QueryResult((gids [b_i, k_i], dists [b_i, k_i]))`` per group, in
-    input order.
+    uses for per-tenant ``BucketStats``.  ``trace`` records
+    ``snapshot``, ``delta_scan``, ``sealed_scan_grouped`` (holding the
+    bucket steps of ``pack_search_blocks_grouped``) and, per group,
+    ``host_topk`` (the exact merge) and ``alive_filter``.
+    Returns one ``QueryResult((gids [b_i, k_i], dists [b_i, k_i]))`` per
+    group, in input order.
     """
     trace = NULL_TRACE if trace is None else trace
     obs = getattr(manager, "obs", None)
@@ -664,8 +667,10 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
             og = np.full((b, g.k), -1, np.int64)
             od = np.full((b, g.k), np.inf, np.float32)
         else:
-            with trace.span("merge", blocks=len(blocks_g[gi]), group=gi):
+            with trace.span("host_topk", blocks=len(blocks_g[gi]),
+                            group=gi):
                 og, od = merge_topk(blocks_g[gi], blocks_d[gi], g.k)
+            with trace.span("alive_filter", group=gi):
                 og, od = _alive_filter(manager, og, od)
         out.append(QueryResult((og, od), degraded=bool(reasons[gi]),
                                reasons=reasons[gi]))

@@ -14,7 +14,8 @@ import pytest
 from repro.core import CubeGraphConfig, IntervalFilter
 from repro.obs import (NULL_METRIC, NULL_REGISTRY, NULL_TRACE, BucketStats,
                        Histogram, MetricsRegistry, QueryTrace, StreamObs,
-                       json_sanitize, prometheus_text)
+                       TraceLog, compile_counts, json_sanitize,
+                       prometheus_text)
 from repro.streaming import SegmentManager, StreamConfig
 
 IDX_CFG = CubeGraphConfig(n_layers=2, m_intra=8, m_cross=3)
@@ -125,11 +126,47 @@ def test_disabled_obs_is_allocation_free():
     """Hammering the disabled registry/trace API allocates (almost)
     nothing: every call returns a pre-built shared singleton."""
     reg = MetricsRegistry(enabled=False)
+
+    def untraced_flush_spans(trace):
+        # the span sites a flush and the grouped path open on NULL_TRACE
+        with trace.span("serve.group"):
+            pass
+        assert not trace.enabled
+        with trace.span("serve.query_grouped"):
+            with trace.span("bucket_dispatch_grouped", cap=8, rows=2,
+                            active_rows=2, n_groups=3, resident=True) as sp:
+                with trace.span("group_stack", groups=3):
+                    pass
+                with trace.span("kernel_launch", groups=1, solo=True):
+                    pass
+                with trace.span("kernel_launch", groups=3):
+                    pass
+                for gi in range(3):
+                    with trace.span("group_split"):
+                        pass
+                    with trace.span("shard_merge", group=gi):
+                        pass
+                with trace.span("device_wait"):
+                    pass
+                for gi in range(3):
+                    with trace.span("readback", group=gi):
+                        pass
+            sp.annotate(candidates=4, cache_hit=True)
+            for gi in range(3):
+                with trace.span("host_topk", blocks=2, group=gi):
+                    pass
+                with trace.span("alive_filter", group=gi):
+                    pass
+        with trace.span("serve.finish", requests=1):
+            with trace.span("materialize"):
+                pass
+
     # warm up any lazy interpreter state before measuring
     reg.counter("a").inc()
     reg.histogram("b").observe(1.0)
     with NULL_TRACE.span("s", attr=1):
         pass
+    untraced_flush_spans(NULL_TRACE)
     tracemalloc.start()
     before = tracemalloc.take_snapshot()
     for _ in range(1000):
@@ -138,11 +175,52 @@ def test_disabled_obs_is_allocation_free():
         reg.histogram("b").observe(1.0)
         with NULL_TRACE.span("s", attr=1) as sp:
             sp.annotate(more=2)
+        untraced_flush_spans(NULL_TRACE)
     after = tracemalloc.take_snapshot()
     tracemalloc.stop()
     grown = sum(st.size_diff for st in after.compare_to(before, "filename")
                 if st.size_diff > 0)
     assert grown < 16 * 1024, f"disabled obs path allocated {grown} bytes"
+
+
+def test_compile_events_count_and_land_on_the_innermost_span():
+    """The listener counts a persistent-cache load once (not also as the
+    compile JAX times around it), a real compile once, and adds each to
+    the innermost open span of its thread; the finished root carries
+    the tree's totals."""
+    import jax
+    from repro.obs.trace import CACHE_LOAD_EVENT, COMPILE_EVENT
+    c0 = compile_counts()
+    trace = QueryTrace("t")
+    with trace.span("outer"):
+        with trace.span("inner") as inner:
+            jax.monitoring.record_event(CACHE_LOAD_EVENT)
+            jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+        jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+    trace.finish()
+    c1 = compile_counts()
+    assert c1["compiles"] - c0["compiles"] == 2
+    assert c1["cache_loads"] - c0["cache_loads"] == 1
+    assert inner.attrs == {"cache_loads": 1}
+    outer = trace.root.children[0]
+    assert outer.attrs == {"compiles": 1}
+    assert trace.root.attrs == {"compiles": 2, "cache_loads": 1}
+    # a closed trace takes no more events
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 0.5)
+    assert trace.root.attrs == {"compiles": 2, "cache_loads": 1}
+    counters = StreamObs().snapshot()["metrics"]["counters"]
+    assert counters["xla_compiles_total"] == c1["compiles"] + 1
+    assert counters["xla_cache_loads_total"] == c1["cache_loads"]
+
+
+def test_trace_log_keeps_the_newest():
+    log = TraceLog(maxlen=2)
+    traces = [QueryTrace(f"t{i}").finish() for i in range(3)]
+    for t in traces:
+        log.add(t)
+    assert len(log) == 2 and log.dropped == 1
+    assert log.traces() == traces[1:]
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ The load-bearing claims here are *equalities*, not trends:
   regardless of what another tenant inserts/deletes concurrently —
   isolation is correctness, not best-effort filtering.
 """
+import dataclasses
 import threading
 
 import numpy as np
@@ -20,6 +21,7 @@ import pytest
 
 from repro.core import (BallFilter, BoxFilter, ComposeFilter,
                         CubeGraphConfig, IntervalFilter)
+from repro.obs import TraceLog
 from repro.serving.batching import (RetrievalBatcher, RetrievalFailure,
                                     RetrievalRequest, _filter_key)
 from repro.serving.rag import Document, DocumentStore
@@ -380,6 +382,160 @@ def test_concurrent_cross_tenant_race_is_invisible():
         assert np.array_equal(ans.dists, np.asarray(expect_dists,
                                                     np.float32))
         assert [[d.doc_id for d in row] for row in ans.docs] == expect_ids
+
+
+# ---------------------------------------------------------------------------
+# Traced flush: the serve.flush span tree
+# ---------------------------------------------------------------------------
+FLUSH_STEPS = {"serve.group", "serve.query_grouped", "serve.finish"}
+QUERY_STEPS = {"snapshot", "delta_scan", "sealed_scan_grouped", "host_topk",
+               "alive_filter"}
+BUCKET_STEPS = {"group_stack", "kernel_launch", "group_split",
+                "shard_merge", "device_wait", "readback"}
+
+
+def _requests(rng, base, n=12, k=(5, 10)):
+    filters = _filters()
+    return [ServeRequest(req_id=base + rid, tenant=("a", "b")[rid % 2],
+                         query_emb=rng.standard_normal(D)
+                         .astype(np.float32),
+                         filt=filters[rid % 3], k=k[rid % len(k)])
+            for rid in range(n)]
+
+
+def _traced_flush(svc, reqs):
+    log = TraceLog()
+    svc.trace_log = log
+    try:
+        for r in reqs:
+            assert svc.submit(r) is None
+        answers = svc.flush()
+    finally:
+        svc.trace_log = None
+    assert len(log) == 1
+    return answers, log.traces()[0]
+
+
+def test_traced_flush_bit_equals_untraced():
+    """Tracing a flush changes no answer: gids, dists and documents are
+    bit-for-bit those of the same requests flushed untraced."""
+    rng = np.random.default_rng(12)
+    store = _two_tenant_store(rng)
+    svc = CubeGraphService(store)
+    reqs = _requests(rng, 0)
+    for r in reqs:
+        assert svc.submit(r) is None
+    plain = svc.flush()
+    again = [dataclasses.replace(r, req_id=r.req_id + 100, enqueued_at=0.0)
+             for r in reqs]
+    traced, trace = _traced_flush(svc, again)
+    assert trace.root.attrs["requests"] == len(reqs)
+    for r in reqs:
+        a, b = plain[r.req_id], traced[r.req_id + 100]
+        assert np.array_equal(a.gids, b.gids)
+        assert np.array_equal(a.dists, b.dists)
+        assert [d.doc_id for d in a.docs] == [d.doc_id for d in b.docs]
+
+
+def test_traced_flush_span_tree_accounts_for_the_flush():
+    """A traced flush records the documented span tree, and the direct
+    children of ``serve.flush`` cover at least 95% of it (best of 3)."""
+    rng = np.random.default_rng(13)
+    store = _two_tenant_store(rng)
+    svc = CubeGraphService(store)
+    for r in _requests(rng, 0):                 # compile outside the trace
+        assert svc.submit(r) is None
+    svc.flush()
+    best = 0.0
+    for rnd in range(3):
+        _, trace = _traced_flush(svc, _requests(rng, 100 * (rnd + 1)))
+        root = trace.root
+        assert root.name == "serve.flush"
+        assert {"requests", "groups", "queue_wait_ms", "compiles",
+                "cache_loads"} <= set(root.attrs)
+        assert root.attrs["groups"] == 6 and root.attrs["queue_wait_ms"] > 0
+        assert {c.name for c in root.children} == FLUSH_STEPS
+        qg = [c for c in root.children if c.name == "serve.query_grouped"]
+        assert len(qg) == 1
+        assert {c.name for c in qg[0].children} == QUERY_STEPS
+        buckets = [s for s in root.walk()
+                   if s.name == "bucket_dispatch_grouped"]
+        assert buckets
+        for b in buckets:
+            assert {c.name for c in b.children} == BUCKET_STEPS
+        for fin in (c for c in root.children if c.name == "serve.finish"):
+            assert [c.name for c in fin.children] == ["materialize"]
+        covered = sum(c.duration_ms for c in root.children)
+        assert covered <= root.duration_ms * (1 + 1e-6)
+        best = max(best, covered / root.duration_ms)
+        if best >= 0.95:
+            break
+    assert best >= 0.95, f"flush steps cover only {best:.1%} of the flush"
+
+
+def test_traced_flush_annotations_share_the_profiler_clock(monkeypatch):
+    """Every span of a flush opens a ``cubegraph.<name>`` profiler
+    annotation on the flushing thread, while the exported tree keeps the
+    bare names."""
+    import jax
+    rng = np.random.default_rng(14)
+    store = _two_tenant_store(rng)
+    svc = CubeGraphService(store)
+    for r in _requests(rng, 0):
+        assert svc.submit(r) is None
+    svc.flush()
+    opened = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            opened.append((name, threading.get_ident()))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    _, trace = _traced_flush(svc, _requests(rng, 100))
+    spans = list(trace.root.walk())
+    assert [name for name, _ in opened] == \
+        ["cubegraph." + s.name for s in spans]
+    assert {tid for _, tid in opened} == {threading.get_ident()}
+    assert trace.to_dict()["name"] == "serve.flush"
+
+
+def test_compile_in_traced_flush_names_its_step():
+    """A shape first seen inside a traced flush (a new k) compiles: the
+    compile lands on the step that caused it, on the flush root, and in
+    the process-wide ``xla_compiles_total``."""
+    rng = np.random.default_rng(15)
+    store = _two_tenant_store(rng)
+    svc = CubeGraphService(store)
+    for r in _requests(rng, 0):
+        assert svc.submit(r) is None
+    svc.flush()
+    counters = store.manager.stats()["obs"]["metrics"]["counters"]
+    before = counters["xla_compiles_total"]
+    _, trace = _traced_flush(svc, _requests(rng, 100, k=(17,)))
+    root = trace.root
+    assert root.attrs["compiles"] > 0
+    assert root.attrs["compiles"] == sum(
+        s.attrs.get("compiles", 0) for s in root.walk() if s is not root)
+    steps = {s.name for s in root.walk()
+             if s is not root and s.attrs.get("compiles", 0)}
+    assert {"kernel_launch", "shard_merge"} <= steps
+    # compiles land on the innermost open span only
+    assert not any(s.attrs.get("compiles", 0) for s in root.walk()
+                   if s.name in ("serve.query_grouped",
+                                 "sealed_scan_grouped",
+                                 "bucket_dispatch_grouped"))
+    after = store.manager.stats()["obs"]["metrics"]["counters"]
+    assert after["xla_compiles_total"] - before >= root.attrs["compiles"]
+    # the same requests again: every shape is warm
+    _, trace = _traced_flush(svc, _requests(rng, 200, k=(17,)))
+    assert trace.root.attrs["compiles"] == 0
+    assert trace.root.attrs["cache_loads"] == 0
 
 
 # ---------------------------------------------------------------------------
