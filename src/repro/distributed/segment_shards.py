@@ -70,7 +70,7 @@ from ..kernels import (PAD_META, dispatch_trace_count, next_pow2,
                        quant_meta_rows, round_up, sharded_filtered_topk,
                        sharded_filtered_topk_grouped,
                        sharded_quant_filtered_topk)
-from ..kernels.ops import place_rows
+from ..kernels.ops import encode_filter, place_rows
 from ..obs.trace import NULL_TRACE, block_ready
 
 __all__ = ["BucketedShardPack", "PackView", "SegmentShardSource",
@@ -1226,7 +1226,8 @@ def pack_search_blocks_grouped(view: PackView, groups,
                                metric: str = "l2", trace=None,
                                observe=None, on_cold=None,
                                deadlines=None, on_expired=None,
-                               fault=None, observe_group=None
+                               fault=None, observe_group=None,
+                               on_device_merge=None
                                ) -> List[List[Tuple[np.ndarray, np.ndarray]]]:
     """Heterogeneous-request sibling of :func:`pack_search_blocks`: several
     ``(queries, filt, k, t_lo, t_hi)`` request groups scan the pack's fp32
@@ -1235,15 +1236,17 @@ def pack_search_blocks_grouped(view: PackView, groups,
 
     Per bucket, the groups whose temporal window intersects the bucket
     (exactly the groups for which a solo :func:`pack_search_blocks` call
-    would dispatch it) are batched into one
-    :func:`repro.kernels.sharded_filtered_topk_grouped` call — the bucket's
-    ``[rows, cap, ·]`` block is read once, not once per distinct filter —
-    and each group's shard-local lists are merged with the group's own
-    temporal ``active`` mask and ``k``.  Because the grouped kernel
-    dispatch is a ``vmap`` of the solo dispatch over the group axis, every
-    group's candidate block is **bit-for-bit** what its solo call would
-    have produced; callers may therefore merge the returned blocks exactly
-    as if each group had scanned alone.
+    would dispatch it) are classed by filter kind and kernel ``kpad``, and
+    each class is finished on the device by one
+    :func:`repro.kernels.sharded_filtered_topk_grouped` program — the
+    kernel over the bucket's ``[rows, cap, ·]`` block, read once per class
+    rather than once per filter, then every group's shard merge with its
+    own temporal ``active`` mask and ``k``.  The bucket then waits once and
+    copies every class's results to the host once; each group's block is a
+    numpy view of them.  Every group's candidate block is **bit-for-bit**
+    what its solo call would have produced, so callers merge the returned
+    blocks exactly as if each group had scanned alone.  A group whose
+    filter has no kernel encoding takes the solo dispatch and merge.
 
     ``deadlines`` (parallel to ``groups``, entries with an ``expired()``
     method or ``None``) drops a group from all remaining buckets once its
@@ -1253,26 +1256,29 @@ def pack_search_blocks_grouped(view: PackView, groups,
     ``observe`` gets one union observation per bucket (cache accounting),
     while ``observe_group(group_idx, cap, rows=, active_rows=,
     candidates=, candidate_slots=, cache_hit=)`` attributes the same
-    dispatch per group — the per-tenant ``BucketStats`` hook.  Returns one
-    candidate-block list per group (a dropped group keeps the blocks
-    gathered before its deadline expired).
+    dispatch per group — the per-tenant ``BucketStats`` hook;
+    ``on_device_merge(n)`` is told once how many groups the on-device
+    finish answered.  Returns one candidate-block list per group (a
+    dropped group keeps the blocks gathered before its deadline expired).
 
     ``trace`` opens one ``bucket_dispatch_grouped`` span per dispatched
-    bucket holding the host's steps: the kernel wrapper's
-    ``group_stack`` / ``kernel_launch`` / ``group_split``, one
-    ``shard_merge`` per group (its merge program's dispatch),
+    bucket holding the host's steps: per class ``group_stack`` (host
+    build and upload) and ``kernel_launch`` (the class's program; with
+    ``solo=True`` a group without a kernel encoding), then
     ``device_wait`` (the bucket's one wait for the device, which the
-    untraced path makes too) and one ``readback`` per group (its
-    device-to-host copies and candidate count).
+    untraced path makes too) and ``readback`` (the bucket's one
+    device-to-host copy, the per-group views and candidate counts).
     """
     trace = NULL_TRACE if trace is None else trace
     groups = [(np.atleast_2d(np.asarray(q, np.float32)), f, int(k),
                float(t_lo), float(t_hi)) for q, f, k, t_lo, t_hi in groups]
+    encs = [encode_filter(f, view.m) for _, f, _, _, _ in groups]
     want_obs = (observe is not None or observe_group is not None
                 or trace.enabled)
     blocks: List[List[Tuple[np.ndarray, np.ndarray]]] = \
         [[] for _ in groups]
     expired = [False] * len(groups)
+    on_device = set()
     buckets = list(view.buckets)
     for bi, bv in enumerate(buckets):
         if deadlines is not None:
@@ -1303,52 +1309,74 @@ def pack_search_blocks_grouped(view: PackView, groups,
             on_cold(bv.cap, bv.stage_bytes)
         union_active = int(np.logical_or.reduce(
             [actives[gi] for gi in live]).sum())
+        kk = {gi: min(groups[gi][2], bv.cap) for gi in live}
+        k_out = {gi: min(groups[gi][2], rows * kk[gi]) for gi in live}
+        classes: Dict[tuple, List[int]] = {}
+        solo: List[int] = []
+        for gi in live:
+            if encs[gi] is None:
+                solo.append(gi)
+            else:
+                classes.setdefault((encs[gi][0], next_pow2(max(kk[gi], 8))),
+                                   []).append(gi)
         traces0 = dispatch_trace_count() if want_obs else 0
         with trace.span("bucket_dispatch_grouped", cap=bv.cap, rows=rows,
                         active_rows=union_active, n_groups=len(live),
                         resident=bv.resident) as sp:
-            sub = [(groups[gi][0], groups[gi][1], min(groups[gi][2], bv.cap))
-                   for gi in live]
-            results = sharded_filtered_topk_grouped(sub, bv.x, bv.s,
-                                                    metric=metric, m=view.m,
-                                                    trace=trace)
-            merged = []
-            for (ids, dd), gi in zip(results, live):
-                with trace.span("shard_merge", group=gi):
-                    kk = min(groups[gi][2], bv.cap)
-                    k_out = min(groups[gi][2], rows * kk)
-                    merged.append(_merge_shard_topk(
-                        ids, dd, bv.gids, jnp.asarray(actives[gi]), k_out))
+            # per class (members, [G, bq_pad, k_top] device results); a
+            # solo group's results are [bq, k_out]
+            pending = [(members, sharded_filtered_topk_grouped(
+                [groups[gi][0] for gi in members], kind,
+                [encs[gi][1] for gi in members], [kk[gi] for gi in members],
+                [actives[gi] for gi in members], bv.x, bv.s, bv.gids,
+                metric=metric, trace=trace))
+                for (kind, _), members in classes.items()]
+            for gi in solo:
+                with trace.span("kernel_launch", groups=1, solo=True):
+                    ids, dd = sharded_filtered_topk(
+                        groups[gi][0], bv.x, bv.s, groups[gi][1], kk[gi],
+                        metric=metric, m=view.m)
+                    pending.append(([gi], _merge_shard_topk(
+                        ids, dd, bv.gids, jnp.asarray(actives[gi]),
+                        k_out[gi])))
             with trace.span("device_wait"):
-                block_ready(merged[-1])
+                block_ready([res for _, res in pending])
             cache_hit = (dispatch_trace_count() == traces0) if want_obs \
                 else False
             n_cand_total = 0
-            for (out_g, out_d), gi in zip(merged, live):
-                with trace.span("readback", group=gi):
-                    out_g = np.asarray(out_g, np.int64)
-                    out_d = np.asarray(out_d, np.float32)
-                    blocks[gi].append((out_g, out_d))
-                    if want_obs:
-                        n_cand = int((out_g >= 0).sum())
-                        n_cand_total += n_cand
-                        if observe_group is not None:
-                            observe_group(
-                                gi, bv.cap, rows=rows,
-                                active_rows=int(actives[gi].sum()),
-                                candidates=n_cand,
-                                candidate_slots=(out_g.shape[0]
-                                                 * out_g.shape[1]),
-                                cache_hit=cache_hit)
+            with trace.span("readback"):
+                host = jax.device_get([res for _, res in pending])
+                for (members, _), (hg, hd) in zip(pending, host):
+                    hg = hg.astype(np.int64)
+                    if hg.ndim == 2:              # a solo group's [bq, k]
+                        hg, hd = hg[None], hd[None]
+                    for j, gi in enumerate(members):
+                        b = groups[gi][0].shape[0]
+                        out_g = hg[j, :b, :k_out[gi]]
+                        blocks[gi].append((out_g, hd[j, :b, :k_out[gi]]))
+                        if want_obs:
+                            n_cand = int((out_g >= 0).sum())
+                            n_cand_total += n_cand
+                            if observe_group is not None:
+                                observe_group(
+                                    gi, bv.cap, rows=rows,
+                                    active_rows=int(actives[gi].sum()),
+                                    candidates=n_cand,
+                                    candidate_slots=b * k_out[gi],
+                                    cache_hit=cache_hit)
+        for members in classes.values():
+            on_device.update(members)
         if want_obs:
             sp.annotate(candidates=n_cand_total, cache_hit=cache_hit)
             if observe is not None:
                 observe(bv.cap, rows=rows, active_rows=union_active,
                         candidates=n_cand_total,
                         candidate_slots=sum(
-                            g.shape[0] * g.shape[1]
-                            for g, _ in (blocks[gi][-1] for gi in live)),
+                            groups[gi][0].shape[0] * k_out[gi]
+                            for gi in live),
                         cache_hit=cache_hit)
+    if on_device_merge is not None:
+        on_device_merge(len(on_device))
     return blocks
 
 

@@ -425,16 +425,91 @@ def sharded_filtered_topk(q, xs, ss, filt: Optional[Filter], k: int,
     return ids[:, :bq, :k], dd[:, :bq, :k]
 
 
+_I32_MAX = int(np.iinfo(np.int32).max)
+
+
+def _flip_negative(bits):
+    """Map float32 bit patterns (as int32) to keys whose signed order is
+    the floats' total order — the order ``lax.top_k`` ranks by, -0.0
+    below +0.0 and NaNs at the ends.  The map is its own inverse."""
+    return jnp.where(bits < 0, bits ^ _I32_MAX, bits)
+
+
+def _merge_lists(ids, dd, valid, gid_stack, k: int):
+    """One group's shard-local lists -> its exact global top-k, by ``k``
+    rounds of least-pair extraction instead of a sort.
+
+    ``ids`` / ``dd`` / ``valid`` are ``[rows, b, w]`` (ids into each row of
+    ``gid_stack [rows, cap]``, -1 for misses); invalid candidates count as
+    +inf.  Each round takes, per query row, the least (distance, position)
+    pair past the previous round's: distances in the total order that
+    ``lax.top_k`` ranks by, position ``shard * w + rank`` (the index in the
+    shard-major concatenation, so ties go to the lower position, as
+    ``top_k``'s do).  The result is therefore bit-for-bit ``top_k`` over
+    the concatenated lists, the solo merge's.  A sort costs about 300 KB
+    of TPU code in each program that holds one, and every group count
+    compiles its own program; the rounds cost about a tenth of that.
+    Returns ``(gids [b, k], dists [b, k])``, gid -1 wherever the distance
+    is not finite."""
+    _, b, w = dd.shape
+    key = _flip_negative(jax.lax.bitcast_convert_type(
+        jnp.where(valid, dd, jnp.inf), jnp.int32))
+    pos = (jax.lax.broadcasted_iota(jnp.int32, dd.shape, 0) * w
+           + jax.lax.broadcasted_iota(jnp.int32, dd.shape, 2))
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, k), 1)
+
+    def extract(j, carry):
+        v0, p0, out_k, out_p, out_i = carry
+        v0, p0 = v0[None, :, None], p0[None, :, None]
+        left = (key > v0) | ((key == v0) & (pos > p0))
+        v = jnp.min(jnp.where(left, key, _I32_MAX), axis=(0, 2))
+        p = jnp.min(jnp.where(left & (key == v[None, :, None]), pos,
+                              _I32_MAX), axis=(0, 2))
+        i = jnp.max(jnp.where(pos == p[None, :, None], ids, -1), axis=(0, 2))
+        at = col == j
+        return (v, p, jnp.where(at, v[:, None], out_k),
+                jnp.where(at, p[:, None], out_p),
+                jnp.where(at, i[:, None], out_i))
+
+    none = jnp.zeros((b, k), jnp.int32)
+    init = (jnp.full((b,), -_I32_MAX - 1, jnp.int32),
+            jnp.full((b,), -1, jnp.int32), none, none, none)
+    _, _, out_k, out_p, out_i = jax.lax.fori_loop(0, k, extract, init)
+    dists = jax.lax.bitcast_convert_type(_flip_negative(out_k), jnp.float32)
+    gids = gid_stack[out_p // w, jnp.maximum(out_i, 0)]
+    return jnp.where(jnp.isfinite(dists), gids, -1), dists
+
+
+def _grouped_k_top(kpad: int, rows: int, cap: int) -> int:
+    """Width of a grouped dispatch's merged lists: the largest
+    ``k_out = min(k, rows * kk)`` (``kk = min(k, cap)``) that a group of
+    the ``kpad`` class can ask of a ``[rows, cap]`` block.  Below the
+    capacity ``kk = k <= kpad``; a class that reaches it (``cap <= kpad``)
+    may hold any ``k >= cap``, so it takes every shard's whole list."""
+    return kpad if cap > kpad else rows * cap
+
+
 @functools.lru_cache(maxsize=None)
 def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
-                             tn: int, mesh=None):
-    """Multi-group sibling of :func:`_sharded_kernel_dispatch`: one jitted
-    dispatch that vmaps the fused kernel over a *group* axis of
-    ``(queries, filter params)`` pairs on top of the usual shard axis, so a
-    heterogeneous-filter batch scans a bucket's device block once instead
-    of once per distinct filter.  Groups sharing a dispatch must share the
-    static config (filter kind, kpad, tiles) — the wrappers class groups by
-    exactly that key."""
+                             tn: int, k_top: int, mesh=None):
+    """One filter class of a grouped bucket dispatch, finished in ONE
+    jitted program: the fused kernel vmapped over a *group* axis of
+    ``(queries, filter params)`` pairs on top of the shard axis (a
+    heterogeneous-filter batch reads the bucket's block once per class,
+    not once per filter), then each group's shard lists merged into its
+    global top-k through the bucket's gid table.
+
+    The merge masks what the group's solo call would not see — shards its
+    temporal ``active`` mask drops and list ranks at or past its own
+    ``kk`` (data, not shape) — and takes :func:`_merge_lists` at
+    ``k_top``; the group keeps its first ``k_out`` columns, bit-for-bit the
+    solo merge of the lists cut to ``kk`` at ``k_out`` (the masked entries
+    are +inf, and a list past its valid entries holds only +inf, whose gid
+    is -1 whichever entry is taken).  The groups are merged one after the
+    other (``lax.map``), which keeps the program's code the same size for
+    any group count.  Shapes follow (kind, kpad, tiles, k_top, mesh) and
+    the arguments' shapes — groups, padded query rows, bucket geometry —
+    never the per-group k or filter values."""
     def scan(qps, xp, sp, pjs):
         def per_group(qp, pj):
             def one(x, s):
@@ -447,95 +522,66 @@ def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
     scan = _on_mesh(scan, mesh, (P(), P("shard"), P("shard"), P()),
                     (P(None, "shard"), P(None, "shard")))
 
-    def call(qps, xp, sp, pjs):
+    def call(qps, pjs, active, kk, xs, ss, gids):
         _TRACE_COUNT[0] += 1             # python side-effect: trace time only
-        return scan(qps, xp, sp, pjs)
+        xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tn, 0.0)
+        sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tn, _PAD_META)
+        dd, ids = scan(qps, xp, sp, pjs)
+        rank = jax.lax.broadcasted_iota(jnp.int32, ids.shape[1:], 2)
+
+        def merge(group):
+            g_ids, g_dd, g_active, g_kk = group
+            valid = (g_ids >= 0) & g_active[:, None, None] & (rank < g_kk)
+            return _merge_lists(g_ids, g_dd, valid, gids, k_top)
+        return jax.lax.map(merge, (ids, dd, active, kk))
     return jax.jit(call)
 
 
-def sharded_filtered_topk_grouped(groups, xs, ss, metric: str = "l2",
-                                  use_kernel: bool = True, tq: int = 64,
-                                  tn: int = 256, m: Optional[int] = None,
-                                  trace=None):
-    """Heterogeneous-filter shard-stack scan: several ``(q, filt, k)``
-    request groups against ONE ``[g, n, d]`` / ``[g, n, m]`` shard stack.
+def sharded_filtered_topk_grouped(qs, kind: str, params, ks, active, xs, ss,
+                                  gids, metric: str = "l2", tq: int = 64,
+                                  tn: int = 256, trace=None):
+    """One filter class of request groups against ONE ``[rows, cap, d]`` /
+    ``[rows, cap, m]`` shard stack, kernel and shard merge in one program.
 
-    ``groups`` is a sequence of ``(q [bq_i, d], filt_i, k_i)`` tuples.
-    Groups whose filters share a kernel encoding class — same filter
-    ``kind`` and same ``kpad = next_pow2(max(k, 8))`` — are stacked on a
-    *group* axis (queries padded to the widest group's padded row count,
-    one packed ``[4, 128]`` parameter block per group) and dispatched as a
-    single vmapped kernel call per class, so the stack's device blocks are
-    read once per class instead of once per request group.  Singleton
-    classes and groups whose filters have no kernel encoding go through
-    :func:`sharded_filtered_topk` unchanged.
+    ``qs`` holds each group's ``[bq_i, d]`` queries, ``params`` its packed
+    ``[4, 128]`` filter (:func:`encode_filter`, all of kind ``kind``),
+    ``ks`` its kernel width ``kk_i = min(k_i, cap)`` (all of one class:
+    the same ``kpad = next_pow2(max(kk_i, 8))``) and ``active`` its
+    ``[rows]`` temporal row mask; ``gids [rows, cap]`` maps shard-local ids
+    to global ones.  The inputs are built on the host in numpy (queries
+    zero-padded to the 128-lane width and to the widest group's padded
+    row count) and uploaded with one ``device_put``; the kernel dispatch
+    is a ``vmap`` of the solo one over the group axis, so each group's
+    lists are what its solo :func:`sharded_filtered_topk` call computes.
 
-    Returns a list of ``(ids [g, bq_i, k_i], dists [g, bq_i, k_i])``
-    aligned with ``groups``.  Each entry is **bit-for-bit** what
-    ``sharded_filtered_topk(q_i, xs, ss, filt_i, k_i)`` returns alone: the
-    kernel computes every query row independently (zero-padded rows and
-    sibling groups cannot perturb a row's distances), and a class shares
-    the per-group static config with the solo dispatch, so the vmapped
-    call runs the identical computation per group.
+    Returns device ``(gids [G, bq_pad, k_top], dists [G, bq_pad, k_top])``
+    (:func:`_grouped_k_top`): group ``i``'s answer is
+    ``[i, :bq_i, :k_out_i]`` with ``k_out_i = min(k_i, rows * kk_i)``,
+    bit-for-bit the solo ``sharded_filtered_topk`` + exact shard merge at
+    ``k_out_i``.  Nothing waits for the device.
 
-    ``trace`` (a ``repro.obs.trace.QueryTrace``, default off) times the
-    host's steps: ``group_stack`` (filter encoding; each class's padding
-    and stacking), ``kernel_launch`` (the jitted call, which returns once
-    the work is enqueued; a solo dispatch with ``solo=True``) and one
-    ``group_split`` per stacked group (its eager result slices).
+    ``trace`` (a ``repro.obs.trace.QueryTrace``, default off) times
+    ``group_stack`` (the host build and upload) and ``kernel_launch``
+    (the jitted call, which returns once the work is enqueued).
     """
     trace = NULL_TRACE if trace is None else trace
-    groups = list(groups)
-    xs = jnp.asarray(xs, jnp.float32)
-    ss = jnp.asarray(ss, jnp.float32)
-    m = ss.shape[2] if m is None else int(m)
-    out: list = [None] * len(groups)
-    solo: list = []
-    classes: "OrderedDict[tuple, list]" = OrderedDict()
-    with trace.span("group_stack", groups=len(groups)):
-        for i, (q, filt, k) in enumerate(groups):
-            enc = encode_filter(filt, m) if use_kernel else None
-            if enc is None:
-                solo.append(i)
-                continue
-            kind, params = enc
-            kpad = _next_pow2(max(int(k), 8))
-            classes.setdefault((kind, kpad), []).append(
-                (i, q, params, int(k)))
-    solo += [members[0][0] for members in classes.values()
-             if len(members) == 1]
-    for i in solo:
-        q, filt, k = groups[i]
-        with trace.span("kernel_launch", groups=1, solo=True):
-            out[i] = sharded_filtered_topk(
-                q, xs, ss, filt, int(k), metric=metric,
-                use_kernel=use_kernel, tq=tq, tn=tn, m=m)
-    for (kind, kpad), members in classes.items():
-        if len(members) == 1:
-            continue
-        with trace.span("group_stack", groups=len(members)):
-            tnk = max(tn, kpad)
-            qps, bqs = [], []
-            for _, q, _, _ in members:
-                q = jnp.asarray(q, jnp.float32)
-                bqs.append(q.shape[0])
-                qps.append(_pad_to(_pad_to(q, 1, 128, 0.0), 0, tq, 0.0))
-            bq_pad = max(qp.shape[0] for qp in qps)
-            qps = jnp.stack([qp if qp.shape[0] == bq_pad
-                             else jnp.pad(qp, ((0, bq_pad - qp.shape[0]),
-                                               (0, 0)))
-                             for qp in qps])
-            pjs = jnp.stack([jnp.asarray(p) for _, _, p, _ in members])
-            xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tnk, 0.0)
-            sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tnk, _PAD_META)
-        with trace.span("kernel_launch", groups=len(members)):
-            dd, ids = _grouped_kernel_dispatch(kind, kpad, metric, tq, tnk,
-                                               block_mesh(xp))(qps, xp, sp,
-                                                               pjs)
-        for gi, (i, _, _, k) in enumerate(members):
-            with trace.span("group_split"):
-                out[i] = (ids[gi, :, :bqs[gi], :k], dd[gi, :, :bqs[gi], :k])
-    return out
+    n_groups = len(qs)
+    rows, cap = int(gids.shape[0]), int(gids.shape[1])
+    kpad = _next_pow2(max(max(ks), 8))
+    with trace.span("group_stack", groups=n_groups):
+        dpad = round_up(int(xs.shape[2]), 128)
+        bq_pad = round_up(max(int(q.shape[0]) for q in qs), tq)
+        qps = np.zeros((n_groups, bq_pad, dpad), np.float32)
+        for i, q in enumerate(qs):
+            qps[i, :q.shape[0], :q.shape[1]] = q
+        args = jax.device_put((qps, np.stack(params),
+                               np.stack(active).astype(bool),
+                               np.asarray(ks, np.int32)))
+    with trace.span("kernel_launch", groups=n_groups):
+        return _grouped_kernel_dispatch(
+            kind, kpad, metric, tq, max(tn, kpad),
+            _grouped_k_top(kpad, rows, cap), block_mesh(xs))(
+                *args, xs, ss, gids)
 
 
 def quant_meta_rows(m: int) -> int:

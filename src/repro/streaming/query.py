@@ -567,7 +567,10 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
     uses for per-tenant ``BucketStats``.  ``trace`` records
     ``snapshot``, ``delta_scan``, ``sealed_scan_grouped`` (holding the
     bucket steps of ``pack_search_blocks_grouped``) and, per group,
-    ``host_topk`` (the exact merge) and ``alive_filter``.
+    ``host_topk`` (the exact merge) and ``alive_filter``.  The counter
+    ``grouped_device_merge_groups_total`` counts the groups the shared
+    path finished on the device (its ratio to ``query_batches_total``
+    says how often that path engages).
     Returns one ``QueryResult((gids [b_i, k_i], dists [b_i, k_i]))`` per
     group, in input order.
     """
@@ -648,7 +651,9 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
                     on_expired=lambda gi, n:
                         _degrade(gi, "deadline_sealed_scan", n),
                     fault=lambda: manager._fault("query.bucket"),
-                    observe_group=observe_group)
+                    observe_group=observe_group,
+                    on_device_merge=registry.counter(
+                        "grouped_device_merge_groups_total").inc)
             for gi, bl in enumerate(per):
                 for gg, dd in bl:
                     blocks_g[gi].append(gg)

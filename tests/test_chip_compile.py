@@ -125,19 +125,32 @@ def test_beam_step_kernel_compiles(mosaic, one_chip, c):
 @pytest.mark.parametrize("grouped", [False, True])
 def test_bucket_dispatch_compiles(mosaic, one_chip, grouped):
     """The per-bucket vmapped dispatch over a [16, 65536, ·] block, solo
-    and with 4 request groups."""
+    and with 4 request groups (kernel and shard merge in one program)."""
     from repro.kernels import ops
     blk = (_spec((16, 65536, D), jnp.float32, one_chip),
            _spec((16, 65536, 128), jnp.float32, one_chip))
     if grouped:
-        disp = ops._grouped_kernel_dispatch("box", 16, "l2", TQ, TN)
-        q = _spec((4, TQ, D), jnp.float32, one_chip)
-        p = _spec((4, 4, 128), jnp.float32, one_chip)
+        disp = ops._grouped_kernel_dispatch(
+            "box", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 65536))
+        args = (_spec((4, TQ, D), jnp.float32, one_chip),
+                _spec((4, 4, 128), jnp.float32, one_chip),
+                _spec((4, 16), jnp.bool_, one_chip),
+                _spec((4,), jnp.int32, one_chip), *blk,
+                _spec((16, 65536), jnp.int32, one_chip))
     else:
         disp = ops._sharded_kernel_dispatch("box", 16, "l2", TQ, TN)
-        q = _spec((TQ, D), jnp.float32, one_chip)
-        p = _spec((4, 128), jnp.float32, one_chip)
-    _compiled(disp, q, *blk, p)
+        args = (_spec((TQ, D), jnp.float32, one_chip), *blk,
+                _spec((4, 128), jnp.float32, one_chip))
+    text = _compiled(disp, *args).as_text()
+    # the device trace names the kernel's op after this instruction, behind
+    # one "vmap_" per batching axis
+    assert re.search(r"^\s*%(vmap_)*jit_filtered_topk_kernel_call", text,
+                     re.M)
+    if grouped:
+        # the merge takes its k rounds without a sort: each group count
+        # compiles its own program, and a sort's code (about 300 KB on
+        # the chip) would stay in device memory once per program
+        assert not re.search(r"\bsort\(", text)
 
 
 @pytest.mark.parametrize("mode", ["fp32", "int8"])
@@ -164,6 +177,28 @@ def test_sharded_dispatch_compiles_on_mesh(mosaic, mesh4, mode):
                 _spec((TQ,), jnp.float32, rep))
     text = _compiled(disp, *args).as_text()
     assert not re.search(r"all-gather|all-to-all", text)
+
+
+def test_grouped_dispatch_compiles_on_mesh(mosaic, mesh4):
+    """The grouped program over a 4-device "shard" mesh: each device runs
+    the kernel on its resident rows, and only candidate lists move for
+    the merge — no device holds more than its quarter of the block."""
+    from repro.kernels import ops
+    rows = NamedSharding(mesh4, P("shard"))
+    rep = NamedSharding(mesh4, P())
+    disp = ops._grouped_kernel_dispatch(
+        "box_ball", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 16384),
+        mesh4)
+    c = _compiled(disp, _spec((4, TQ, D), jnp.float32, rep),
+                  _spec((4, 4, 128), jnp.float32, rep),
+                  _spec((4, 16), jnp.bool_, rep),
+                  _spec((4,), jnp.int32, rep),
+                  _spec((16, 16384, D), jnp.float32, rows),
+                  _spec((16, 16384, 128), jnp.float32, rows),
+                  _spec((16, 16384), jnp.int32, rows))
+    quarter_block = 16 * 16384 * D * 4 // 4
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < quarter_block
 
 
 def test_graph_traversal_compiles_on_mesh(mosaic, mesh4):

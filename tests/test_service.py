@@ -390,8 +390,7 @@ def test_concurrent_cross_tenant_race_is_invisible():
 FLUSH_STEPS = {"serve.group", "serve.query_grouped", "serve.finish"}
 QUERY_STEPS = {"snapshot", "delta_scan", "sealed_scan_grouped", "host_topk",
                "alive_filter"}
-BUCKET_STEPS = {"group_stack", "kernel_launch", "group_split",
-                "shard_merge", "device_wait", "readback"}
+BUCKET_STEPS = {"group_stack", "kernel_launch", "device_wait", "readback"}
 
 
 def _requests(rng, base, n=12, k=(5, 10)):
@@ -463,6 +462,12 @@ def test_traced_flush_span_tree_accounts_for_the_flush():
         assert buckets
         for b in buckets:
             assert {c.name for c in b.children} == BUCKET_STEPS
+            # per class one host build and one program; per bucket one
+            # wait and one copy
+            names = [c.name for c in b.children]
+            assert names.count("group_stack") == names.count("kernel_launch")
+            assert names[-2:] == ["device_wait", "readback"]
+            assert names.count("readback") == 1
         for fin in (c for c in root.children if c.name == "serve.finish"):
             assert [c.name for c in fin.children] == ["materialize"]
         covered = sum(c.duration_ms for c in root.children)
@@ -524,7 +529,9 @@ def test_compile_in_traced_flush_names_its_step():
         s.attrs.get("compiles", 0) for s in root.walk() if s is not root)
     steps = {s.name for s in root.walk()
              if s is not root and s.attrs.get("compiles", 0)}
-    assert {"kernel_launch", "shard_merge"} <= steps
+    # the delta scan and the grouped program each compile for the new k;
+    # the host build, the wait and the copy compile nothing
+    assert steps == {"delta_scan", "kernel_launch"}
     # compiles land on the innermost open span only
     assert not any(s.attrs.get("compiles", 0) for s in root.walk()
                    if s.name in ("serve.query_grouped",
