@@ -59,8 +59,7 @@ def _topk_over_candidates(qv, qn, cand, x, norms, exclude, k, col_chunk, metric)
         ids = cand[:, i, :]                                   # [b, c]
         safe = jnp.maximum(ids, 0)
         xv = x[safe]                                          # [b, c, d]
-        # HIGHEST: the int8 path's exact fp32 rerank scores through here,
-        # and a TPU's default f32 matmul is one bf16 pass
+        # HIGHEST: a TPU's default f32 matmul is one bf16 pass
         ip = jnp.einsum("bcd,bd->bc", xv, qv,
                         precision=jax.lax.Precision.HIGHEST)
         if metric == "l2":

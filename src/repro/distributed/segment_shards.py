@@ -617,8 +617,13 @@ class BucketedShardPack:
     def drain_warm_shapes(self) -> List[dict]:
         """Pop the block geometries created since the last drain (call
         under the owner's lock; feed to
-        ``kernels.ops.warm_sharded_shapes`` off the query path)."""
+        ``kernels.ops.warm_sharded_shapes`` off the query path).  Quantized
+        ones carry the pack's bucket count, which sets the rerank's
+        candidate width."""
         out, self._new_shapes = self._new_shapes, []
+        for spec in out:
+            if spec["mode"] == "int8":
+                spec["buckets"] = len(self.buckets)
         return out
 
     def _init_slots(self) -> int:
@@ -1384,7 +1389,7 @@ def pack_search(pack, queries: np.ndarray, filt: Optional[Filter],
                 k: int, t_lo: float = -np.inf, t_hi: float = np.inf,
                 metric: str = "l2", lookup=None,
                 rerank_multiple: int = 4, trace=None,
-                observe=None, on_cold=None
+                observe=None, on_cold=None, registry=None
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Fan one query batch out over every active shard of the pack and merge
     the shard-local top-k exactly.
@@ -1396,8 +1401,11 @@ def pack_search(pack, queries: np.ndarray, filt: Optional[Filter],
     sees one static shape per pack/bucket.  A quantized pack additionally
     needs ``lookup(gids) -> (x, s, present)`` (the manager's point-store
     getter) for the exact fp32 rerank of its over-fetched
-    (``rerank_multiple * k``) candidates.  Returns ``(gids [b, k] int64,
-    dists [b, k] fp32)`` with ``-1`` / ``+inf`` padding.
+    (``rerank_multiple * k``) candidates, padded to ``rerank_multiple * k``
+    slots per bucket of the view whatever the buckets dispatched or the
+    candidates found, so its program's shape is fixed per view geometry;
+    ``registry`` takes the rerank's counters.  Returns ``(gids [b, k]
+    int64, dists [b, k] fp32)`` with ``-1`` / ``+inf`` padding.
     """
     queries = np.atleast_2d(np.asarray(queries, np.float32))
     b = queries.shape[0]
@@ -1422,11 +1430,11 @@ def pack_search(pack, queries: np.ndarray, filt: Optional[Filter],
                                  "exact fp32 rerank")
             from ..quant import rerank_exact
             with trace.span("rerank_fp32", overfetch=int(g.shape[1]),
-                            k=k) as sp:
-                out = rerank_exact(queries, g, k, lookup, metric=metric)
-                block_ready(out)
-                sp.annotate(candidates=int((out[0] >= 0).sum()))
-            return out
+                            k=k):
+                return rerank_exact(queries, g, k, lookup, metric=metric,
+                                    part_width=k_fetch,
+                                    parts=len(view.buckets), trace=trace,
+                                    registry=registry)
         d = np.concatenate([bd for _, bd in blocks], axis=1)
         return host_topk(g, d, k)
     kk = min(k, pack.cap)                 # per-shard list length

@@ -29,7 +29,7 @@ from .quant_topk import quant_filtered_topk_kernel_call
 
 __all__ = ["pairwise_dist", "filtered_topk", "next_pow2", "round_up",
            "sharded_filtered_topk", "sharded_filtered_topk_grouped",
-           "sharded_quant_filtered_topk",
+           "sharded_quant_filtered_topk", "rerank_topk", "rerank_width",
            "quant_meta_rows", "warm_sharded_shapes", "dispatch_trace_count",
            "encode_filter", "exact_filtered_search", "place_rows",
            "PAD_META"]
@@ -280,13 +280,17 @@ def warm_sharded_shapes(specs) -> int:
 
     ``specs`` is an iterable of dicts describing device blocks the pack
     just created: ``{"mode": "fp32", "rows", "cap", "dpad", "mesh"}`` or
-    ``{"mode": "int8", "rows", "cap", "dq", "mq", "mesh"}``.  For every
-    recorded dispatch signature (captured from real queries) whose
-    geometry matches, the jitted dispatch is invoked once on zero arrays
-    of the new shape, built and placed exactly the way the real wrappers
-    build theirs (same padding helpers, same mesh sharding) so the jit
-    cache entry is the one the first post-growth query will hit (the
-    exp12 residual-spike fix).  Returns the number of dispatches warmed.
+    ``{"mode": "int8", "rows", "cap", "dq", "mq", "mesh", "buckets"}``.
+    For every recorded dispatch signature (captured from real queries)
+    whose geometry matches, the jitted dispatch is invoked once on zero
+    arrays of the new shape, built and placed exactly the way the real
+    wrappers build theirs (same padding helpers, same mesh sharding) so
+    the jit cache entry is the one the first post-growth query will hit
+    (the exp12 residual-spike fix).  An int8 spec's ``buckets`` (the
+    pack's bucket count) also warms every recorded rerank signature
+    (:func:`rerank_topk`) at each candidate width a view of 1 to
+    ``buckets`` buckets asks for.  Returns the number of dispatches
+    warmed.
     """
     with _WARM_LOCK:
         sigs = list(_WARM_SIGS)
@@ -295,6 +299,10 @@ def warm_sharded_shapes(specs) -> int:
         rows, cap = int(spec["rows"]), int(spec["cap"])
         mesh = spec.get("mesh")
         for sig in sigs:
+            if sig[0] == "rerank":
+                if spec.get("mode") == "int8":
+                    warmed += _warm_rerank(sig, int(spec.get("buckets", 0)))
+                continue
             mode, kind, kpad, metric, tq, tn, bq_pad = sig[:7]
             if mode != spec.get("mode", "fp32"):
                 continue
@@ -697,6 +705,69 @@ def sharded_quant_filtered_topk(q, codes, st, scales, filt: Optional[Filter],
     dd, ids = _sharded_quant_dispatch(kind, kpad, metric, tq, tn,
                                       block_mesh(cp))(qsp, cp, stp, pt, qnp)
     return ids[:, :bq, :k], dd[:, :bq, :k]
+
+
+def rerank_width(part_width: int, parts: int) -> int:
+    """Candidate slots a rerank of ``parts`` candidate lists of up to
+    ``part_width`` each is padded to: their sum rounded up to the lane
+    width, so the program's shape follows the view's bucket count and
+    never the candidates found."""
+    return round_up(int(part_width) * int(parts), 128)
+
+
+@functools.lru_cache(maxsize=None)
+def _rerank_dispatch(k: int, metric: str):
+    """One jitted exact rerank per (k, metric): float32 distances of each
+    query row's gathered candidate vectors at ``Precision.HIGHEST`` (the
+    expansion ``||x||^2 - 2 x.q + ||q||^2`` of the exact scan), invalid
+    slots at +inf, then ``lax.top_k``, which breaks distance ties toward
+    the lower slot.  Shapes follow the arguments only: padded query rows,
+    padded candidate slots and the dimension."""
+    def call(q, xs, valid):
+        _TRACE_COUNT[0] += 1             # python side-effect: trace time only
+        ip = jnp.einsum("bwd,bd->bw", xs, q,
+                        precision=jax.lax.Precision.HIGHEST)
+        if metric == "l2":
+            d = (jnp.sum(xs * xs, axis=2) - 2.0 * ip
+                 + jnp.sum(q * q, axis=1)[:, None])
+        else:                             # inner product, negated
+            d = -ip
+        neg, pos = jax.lax.top_k(-jnp.where(valid, d, jnp.inf), k)
+        return pos, -neg
+    return jax.jit(call)
+
+
+def rerank_topk(q, xs, valid, k: int, metric: str = "l2",
+                part_width: Optional[int] = None):
+    """Exact top-``k`` of each query row's candidate slots: ``q [b_pad,
+    d]``, ``xs [b_pad, w_pad, d]`` float32 vectors and ``valid [b_pad,
+    w_pad]`` (uploaded, or numpy to be uploaded with the call).  Returns
+    device ``(slots [b_pad, kk], dists [b_pad, kk])``, ``kk = min(k,
+    w_pad)``, nearest first, +inf past the valid slots.
+
+    ``part_width`` (the width of one bucket's candidate list) records the
+    signature in the warm registry, so that a pack that gains a bucket
+    pre-traces the wider rerank (:func:`warm_sharded_shapes`)."""
+    b_pad, w_pad, d = (int(n) for n in xs.shape)
+    kk = min(int(k), w_pad)
+    if part_width is not None:
+        _note_warm_sig(("rerank", metric, int(k), b_pad, int(part_width),
+                        d))
+    return _rerank_dispatch(kk, metric)(q, xs, valid)
+
+
+def _warm_rerank(sig: tuple, buckets: int) -> int:
+    """Pre-trace a recorded rerank signature at the widths of views of 1
+    to ``buckets`` buckets; returns the number of widths warmed."""
+    _, metric, k, b_pad, part_width, d = sig
+    widths = sorted({rerank_width(part_width, p)
+                     for p in range(1, buckets + 1)})
+    for w in widths:
+        _rerank_dispatch(min(k, w), metric)(
+            jnp.zeros((b_pad, d), jnp.float32),
+            jnp.zeros((b_pad, w, d), jnp.float32),
+            jnp.zeros((b_pad, w), bool))
+    return len(widths)
 
 
 def exact_filtered_search(q, x, s, filt: Optional[Filter], k: int,

@@ -10,8 +10,8 @@ same deterministic ``(dist, gid)`` tie-break the unquantized merge uses.
 
 - ``codec``   fit / quantize / dequantize + the per-segment ``SegmentQuant``
               payload (codes, scales, dequantized squared norms)
-- ``rerank``  exact fp32 top-k over a candidate gid set via the existing
-              ``core.graph.topk_over_candidates`` primitive
+- ``rerank``  exact fp32 top-k over a candidate gid set, gathered into a
+              fixed-shape block and scored by ``kernels.ops.rerank_topk``
 """
 from .codec import (QUANT_KINDS, SegmentQuant, dequantize, encode_segment,
                     fit_scales, quantize)

@@ -192,7 +192,7 @@ def _graph_search_blocks(manager, pack, buckets, queries, filt, k,
                     sub, queries, filt, k, t_lo=t_lo, t_hi=t_hi,
                     metric=metric, lookup=manager.get_points,
                     rerank_multiple=cfg.rerank_multiple, trace=trace,
-                    observe=observe, on_cold=on_cold)
+                    observe=observe, on_cold=on_cold, registry=registry)
                 blocks_g.append(gg)
                 blocks_d.append(dd)
             else:
@@ -215,7 +215,8 @@ def _graph_search_blocks(manager, pack, buckets, queries, filt, k,
         with trace.span("graph_rerank",
                         candidates=int(sum(g.shape[1] for g in cand_g))):
             gg, dd = rerank_exact(queries, np.concatenate(cand_g, axis=1),
-                                  k, manager.get_points, metric=metric)
+                                  k, manager.get_points, metric=metric,
+                                  trace=trace, registry=registry)
         blocks_g.append(gg)
         blocks_d.append(dd)
     return blocks_g, blocks_d
@@ -405,7 +406,7 @@ def query_segments(manager, queries: np.ndarray, filt: Optional[Filter],
                                 lookup=manager.get_points,
                                 rerank_multiple=manager.cfg.rerank_multiple,
                                 trace=trace, observe=observe,
-                                on_cold=on_cold)
+                                on_cold=on_cold, registry=registry)
                             blocks_g.append(gg)
                             blocks_d.append(dd)
                         else:
@@ -428,7 +429,8 @@ def query_segments(manager, queries: np.ndarray, filt: Optional[Filter],
                             t_hi=t_hi, metric=metric,
                             lookup=manager.get_points,
                             rerank_multiple=manager.cfg.rerank_multiple,
-                            trace=trace, observe=observe, on_cold=on_cold)
+                            trace=trace, observe=observe, on_cold=on_cold,
+                            registry=registry)
                         blocks_g.append(gg)
                         blocks_d.append(dd)
                 elif isinstance(pack, PackView):

@@ -217,3 +217,16 @@ def test_graph_traversal_compiles_on_mesh(mosaic, mesh4):
         k=10, ef=128, width=8, max_iters=256, kind="box_ball", metric="l2",
         m=4, quantized=False, use_pallas=True, mesh=mesh4).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("w_pad", [128, 256])
+def test_rerank_compiles(one_chip, w_pad):
+    """The int8 path's fp32 rerank at the Deep10M cell's widths: 64 padded
+    query rows of d=96, one or two buckets' 40 over-fetched candidates per
+    row in 128 or 256 slots, k=10: plain XLA, no Mosaic call."""
+    from repro.kernels import ops
+    compiled = jax.jit(ops._rerank_dispatch(10, "l2")).lower(
+        _spec((TQ, 96), jnp.float32, one_chip),
+        _spec((TQ, w_pad, 96), jnp.float32, one_chip),
+        _spec((TQ, w_pad), jnp.bool_, one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
