@@ -1232,7 +1232,7 @@ def pack_search_blocks_grouped(view: PackView, groups,
                                observe=None, on_cold=None,
                                deadlines=None, on_expired=None,
                                fault=None, observe_group=None,
-                               on_device_merge=None
+                               on_device_merge=None, on_kernel_rows=None
                                ) -> List[List[Tuple[np.ndarray, np.ndarray]]]:
     """Heterogeneous-request sibling of :func:`pack_search_blocks`: several
     ``(queries, filt, k, t_lo, t_hi)`` request groups scan the pack's fp32
@@ -1244,11 +1244,12 @@ def pack_search_blocks_grouped(view: PackView, groups,
     would dispatch it) are classed by filter kind and kernel ``kpad``, and
     each class is finished on the device by one
     :func:`repro.kernels.sharded_filtered_topk_grouped` program — the
-    kernel over the bucket's ``[rows, cap, ·]`` block, read once per class
-    rather than once per filter, then every group's shard merge with its
-    own temporal ``active`` mask and ``k``.  The bucket then waits once and
-    copies every class's results to the host once; each group's block is a
-    numpy view of them.  Every group's candidate block is **bit-for-bit**
+    class's query rows stacked in one query axis, each row with its
+    group's filter, so one kernel call reads the bucket's ``[rows, cap,
+    ·]`` block once per class rather than once per filter, then every
+    row's shard merge with its group's temporal ``active`` mask and
+    ``k``.  The bucket then waits once and copies every class's results to
+    the host once; each group's block is a numpy view of its rows.  Every group's candidate block is **bit-for-bit**
     what its solo call would have produced, so callers merge the returned
     blocks exactly as if each group had scanned alone.  A group whose
     filter has no kernel encoding takes the solo dispatch and merge.
@@ -1263,7 +1264,9 @@ def pack_search_blocks_grouped(view: PackView, groups,
     candidates=, candidate_slots=, cache_hit=)`` attributes the same
     dispatch per group — the per-tenant ``BucketStats`` hook;
     ``on_device_merge(n)`` is told once how many groups the on-device
-    finish answered.  Returns one candidate-block list per group (a
+    finish answered, and ``on_kernel_rows(rows, slots)`` once per class
+    program how many real query rows it scanned and how many padded rows
+    its query tiles computed.  Returns one candidate-block list per group (a
     dropped group keeps the blocks gathered before its deadline expired).
 
     ``trace`` opens one ``bucket_dispatch_grouped`` span per dispatched
@@ -1328,37 +1331,43 @@ def pack_search_blocks_grouped(view: PackView, groups,
         with trace.span("bucket_dispatch_grouped", cap=bv.cap, rows=rows,
                         active_rows=union_active, n_groups=len(live),
                         resident=bv.resident) as sp:
-            # per class (members, [G, bq_pad, k_top] device results); a
-            # solo group's results are [bq, k_out]
-            pending = [(members, sharded_filtered_topk_grouped(
-                [groups[gi][0] for gi in members], kind,
-                [encs[gi][1] for gi in members], [kk[gi] for gi in members],
-                [actives[gi] for gi in members], bv.x, bv.s, bv.gids,
-                metric=metric, trace=trace))
-                for (kind, _), members in classes.items()]
+            # per class (members, their first rows, [R_pad, k_top] device
+            # results); a solo group's results are [bq, k_out]
+            pending = []
+            for (kind, _), members in classes.items():
+                out_g, out_d, offs = sharded_filtered_topk_grouped(
+                    [groups[gi][0] for gi in members], kind,
+                    [encs[gi][1] for gi in members],
+                    [kk[gi] for gi in members],
+                    [actives[gi] for gi in members], bv.x, bv.s, bv.gids,
+                    view.m, metric=metric, trace=trace)
+                pending.append((members, offs, (out_g, out_d)))
+                if on_kernel_rows is not None:
+                    on_kernel_rows(sum(groups[gi][0].shape[0]
+                                       for gi in members),
+                                   int(out_g.shape[0]))
             for gi in solo:
                 with trace.span("kernel_launch", groups=1, solo=True):
                     ids, dd = sharded_filtered_topk(
                         groups[gi][0], bv.x, bv.s, groups[gi][1], kk[gi],
                         metric=metric, m=view.m)
-                    pending.append(([gi], _merge_shard_topk(
+                    pending.append(([gi], [0], _merge_shard_topk(
                         ids, dd, bv.gids, jnp.asarray(actives[gi]),
                         k_out[gi])))
             with trace.span("device_wait"):
-                block_ready([res for _, res in pending])
+                block_ready([res for _, _, res in pending])
             cache_hit = (dispatch_trace_count() == traces0) if want_obs \
                 else False
             n_cand_total = 0
             with trace.span("readback"):
-                host = jax.device_get([res for _, res in pending])
-                for (members, _), (hg, hd) in zip(pending, host):
+                host = jax.device_get([res for _, _, res in pending])
+                for (members, offs, _), (hg, hd) in zip(pending, host):
                     hg = hg.astype(np.int64)
-                    if hg.ndim == 2:              # a solo group's [bq, k]
-                        hg, hd = hg[None], hd[None]
-                    for j, gi in enumerate(members):
+                    for gi, off in zip(members, offs):
                         b = groups[gi][0].shape[0]
-                        out_g = hg[j, :b, :k_out[gi]]
-                        blocks[gi].append((out_g, hd[j, :b, :k_out[gi]]))
+                        out_g = hg[off:off + b, :k_out[gi]]
+                        blocks[gi].append((out_g,
+                                           hd[off:off + b, :k_out[gi]]))
                         if want_obs:
                             n_cand = int((out_g >= 0).sum())
                             n_cand_total += n_cand

@@ -97,6 +97,54 @@ def _filter_mask_t(meta_t, pcol, kind):
     return in_box & ~in_ball                       # box_not_ball
 
 
+def param_rows(params, m: int):
+    """Per-row packed filters ``[bq, 4, mpad]`` -> the row layout
+    ``[bq, L]`` the per-row predicate reads (``L`` the lanes of ``3m + 2``
+    columns, rounded up to 128): lanes ``[0, m)`` hold each row's box lo,
+    ``[m, 2m)`` its box hi, ``[2m, 3m)`` its ball center, lane ``3m`` its
+    r^2 and lane ``3m + 1`` its ball ndim, so every operand of
+    :func:`_filter_mask_rows` is a static ``[bq, 1]`` lane slice."""
+    p = jnp.asarray(params, jnp.float32)
+    rows = jnp.concatenate([p[:, 0, :m], p[:, 1, :m], p[:, 2, :m],
+                            p[:, 3, 0:2]], axis=1)
+    lanes = -(-(3 * m + 2) // 128) * 128
+    return jnp.pad(rows, ((0, 0), (0, lanes - rows.shape[1])))
+
+
+def _filter_mask_rows(meta_t, prow, kind, m):
+    """Per-row predicate over points on the lane axis: meta_t ``[mq,
+    tn]``, prow ``[tq, L]`` from :func:`param_rows` -> bool ``[tq, tn]``
+    (``[1, tn]`` for ``"none"``).  Each query row tests its own filter
+    over the ``m`` real metadata dims only (the dims past them pass every
+    shared test, see :func:`_filter_mask_t`), with the shared predicate's
+    float32 operations: inclusive box compares, and the ball's ``diff *
+    diff`` summed over its dims in order, so a row's mask is bit-for-bit
+    what a shared call with its filter computes."""
+    if kind == "none":
+        # padding rows carry meta = +2e30 and must still fail:
+        return meta_t[0:1, :] < _POS
+    in_box = d2 = None
+    nd = prow[:, 3 * m + 1:3 * m + 2]
+    for c in range(m):
+        v = meta_t[c:c + 1, :]                                  # [1, tn]
+        if kind != "ball":
+            inside = ((v >= prow[:, c:c + 1])
+                      & (v <= prow[:, m + c:m + c + 1]))        # [tq, tn]
+            in_box = inside if in_box is None else in_box & inside
+        if kind != "box":
+            diff = v - prow[:, 2 * m + c:2 * m + c + 1]
+            sq = jnp.where(float(c) < nd, diff * diff, 0.0)
+            d2 = sq if d2 is None else d2 + sq
+    if kind == "box":
+        return in_box
+    in_ball = d2 <= prow[:, 3 * m:3 * m + 1]
+    if kind == "ball":
+        return in_ball
+    if kind == "box_ball":
+        return in_box & in_ball
+    return in_box & ~in_ball                       # box_not_ball
+
+
 def interpret_mode() -> bool:
     """Whether Pallas kernels run in the interpreter: on the CPU backend
     (tests, rehearsals) they must; on a TPU they never do — Mosaic
@@ -170,7 +218,8 @@ def _merge_sorted(run_d, run_i, tile_d, tile_i, kpad):
 
 def _fold_tile(d, ok, run_d, run_i, od_ref, oi_ref, *, kpad, tn, n_ctiles):
     """Shared tail of the scan kernels: mask one candidate tile's
-    distances ``d [tq, tn]`` by the predicate ``ok [1, tn]``, fold its
+    distances ``d [tq, tn]`` by the predicate ``ok`` (``[1, tn]`` shared
+    by the query rows, or ``[tq, tn]`` per row), fold its
     top-kpad into the running list in VMEM scratch, and emit the list
     after the last candidate tile (grid axis 1)."""
     j = pl.program_id(1)
@@ -193,7 +242,8 @@ def _fold_tile(d, ok, run_d, run_i, od_ref, oi_ref, *, kpad, tn, n_ctiles):
 
 
 def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
-                  run_d, run_i, *, metric, kind, kpad, tn, n_ctiles):
+                  run_d, run_i, *, metric, kind, kpad, tn, n_ctiles,
+                  row_m):
     q = q_ref[...]
     x = x_ref[...]
     ip = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
@@ -206,21 +256,29 @@ def _fused_kernel(q_ref, x_ref, s_ref, p_ref, od_ref, oi_ref,
              + jnp.sum(xf * xf, axis=1)[None, :])
     else:
         d = -ip
-    ok = _filter_mask_t(jnp.transpose(s_ref[...]), p_ref[...], kind)
+    meta_t = jnp.transpose(s_ref[...])
+    if row_m is None:
+        ok = _filter_mask_t(meta_t, p_ref[...], kind)
+    else:
+        ok = _filter_mask_rows(meta_t, p_ref[...], kind, row_m)
     _fold_tile(d, ok, run_d, run_i, od_ref, oi_ref, kpad=kpad, tn=tn,
                n_ctiles=n_ctiles)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "kind", "kpad", "tq",
-                                             "tn", "interpret"))
+                                             "tn", "m", "interpret"))
 def filtered_topk_kernel_call(q, x, s_pad, params, *, kind: str, kpad: int,
                               metric: str = "l2", tq: int = 64, tn: int = 256,
+                              m: Optional[int] = None,
                               interpret: Optional[bool] = None):
     """Fused filtered top-k.  Pre-padded inputs:
     q [bq, d] (bq % tq == 0, d % 128 == 0), x [n, d] (n % tn == 0),
     s_pad [n, mpad] metadata padded to 128 lanes (+2e30 in padding rows so
     they fail every predicate), params [4, mpad] packed filter
-    (box lo/hi, ball center, [r^2, ball_ndim]).  kpad power of two <= tn.
+    (box lo/hi, ball center, [r^2, ball_ndim]) shared by every query row,
+    or [bq, 4, mpad]: one packed filter per query row, each row tested
+    against its own over the first ``m`` metadata columns (the real ones,
+    required with per-row params).  kpad power of two <= tn.
     Returns (dists [bq, kpad] ascending, ids [bq, kpad], -1 for misses).
     ``interpret=None`` takes the mode from :func:`interpret_mode`.
     """
@@ -231,8 +289,17 @@ def filtered_topk_kernel_call(q, x, s_pad, params, *, kind: str, kpad: int,
     n, mpad = s_pad.shape
     grid = (bq // tq, n // tn)
     width = _lanes(kpad)
+    if params.ndim == 3:
+        assert params.shape[0] == bq and m is not None and 1 <= m <= mpad
+        row_m = m
+        p_in = param_rows(params, m)
+        p_spec = pl.BlockSpec((tq, p_in.shape[1]), lambda i, j: (i, 0))
+    else:
+        row_m = None
+        p_in = param_columns(params)
+        p_spec = pl.BlockSpec((mpad, 8), lambda i, j: (0, 0))
     kern = functools.partial(_fused_kernel, metric=metric, kind=kind,
-                             kpad=kpad, tn=tn, n_ctiles=grid[1])
+                             kpad=kpad, tn=tn, n_ctiles=grid[1], row_m=row_m)
     dd, ids = pl.pallas_call(
         kern,
         grid=grid,
@@ -240,7 +307,7 @@ def filtered_topk_kernel_call(q, x, s_pad, params, *, kind: str, kpad: int,
             pl.BlockSpec((tq, d), lambda i, j: (i, 0)),
             pl.BlockSpec((tn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((tn, mpad), lambda i, j: (j, 0)),
-            pl.BlockSpec((mpad, 8), lambda i, j: (0, 0)),
+            p_spec,
         ],
         out_specs=[
             pl.BlockSpec((tq, width), lambda i, j: (i, 0)),
@@ -257,5 +324,5 @@ def filtered_topk_kernel_call(q, x, s_pad, params, *, kind: str, kpad: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, x, s_pad, param_columns(params))
+    )(q, x, s_pad, p_in)
     return dd[:, :kpad], ids[:, :kpad]

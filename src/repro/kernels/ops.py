@@ -444,8 +444,8 @@ def _flip_negative(bits):
 
 
 def _merge_lists(ids, dd, valid, gid_stack, k: int):
-    """One group's shard-local lists -> its exact global top-k, by ``k``
-    rounds of least-pair extraction instead of a sort.
+    """Query rows' shard-local lists -> each row's exact global top-k, by
+    ``k`` rounds of least-pair extraction instead of a sort.
 
     ``ids`` / ``dd`` / ``valid`` are ``[rows, b, w]`` (ids into each row of
     ``gid_stack [rows, cap]``, -1 for misses); invalid candidates count as
@@ -455,10 +455,9 @@ def _merge_lists(ids, dd, valid, gid_stack, k: int):
     shard-major concatenation, so ties go to the lower position, as
     ``top_k``'s do).  The result is therefore bit-for-bit ``top_k`` over
     the concatenated lists, the solo merge's.  A sort costs about 300 KB
-    of TPU code in each program that holds one, and every group count
-    compiles its own program; the rounds cost about a tenth of that.
-    Returns ``(gids [b, k], dists [b, k])``, gid -1 wherever the distance
-    is not finite."""
+    of TPU code in each program that holds one; the rounds cost about a
+    tenth of that.  Returns ``(gids [b, k], dists [b, k])``, gid -1
+    wherever the distance is not finite."""
     _, b, w = dd.shape
     key = _flip_negative(jax.lax.bitcast_convert_type(
         jnp.where(valid, dd, jnp.inf), jnp.int32))
@@ -499,74 +498,74 @@ def _grouped_k_top(kpad: int, rows: int, cap: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _grouped_kernel_dispatch(kind: str, kpad: int, metric: str, tq: int,
-                             tn: int, k_top: int, mesh=None):
+                             tn: int, k_top: int, m: int, mesh=None):
     """One filter class of a grouped bucket dispatch, finished in ONE
-    jitted program: the fused kernel vmapped over a *group* axis of
-    ``(queries, filter params)`` pairs on top of the shard axis (a
-    heterogeneous-filter batch reads the bucket's block once per class,
-    not once per filter), then each group's shard lists merged into its
-    global top-k through the bucket's gid table.
+    jitted program: the class's query rows, every group's rows stacked in
+    one query axis with its own packed filter on each row, go through ONE
+    fused kernel call per shard (the per-row predicate over the ``m`` real
+    metadata dims, so a 64-row query tile serves up to 64 groups and the
+    bucket's block is read once per class), then every row's shard lists
+    are merged into its global top-k through the bucket's gid table.
 
-    The merge masks what the group's solo call would not see — shards its
-    temporal ``active`` mask drops and list ranks at or past its own
-    ``kk`` (data, not shape) — and takes :func:`_merge_lists` at
-    ``k_top``; the group keeps its first ``k_out`` columns, bit-for-bit the
-    solo merge of the lists cut to ``kk`` at ``k_out`` (the masked entries
-    are +inf, and a list past its valid entries holds only +inf, whose gid
-    is -1 whichever entry is taken).  The groups are merged one after the
-    other (``lax.map``), which keeps the program's code the same size for
-    any group count.  Shapes follow (kind, kpad, tiles, k_top, mesh) and
-    the arguments' shapes — groups, padded query rows, bucket geometry —
-    never the per-group k or filter values."""
-    def scan(qps, xp, sp, pjs):
-        def per_group(qp, pj):
-            def one(x, s):
-                return filtered_topk_kernel_call(qp, x, s, pj, kind=kind,
-                                                 kpad=kpad, metric=metric,
-                                                 tq=tq, tn=tn)
-            return jax.vmap(one)(xp, sp)
-        return jax.vmap(per_group)(qps, pjs)
+    The merge masks what the row's solo call would not see — shards its
+    group's temporal ``active`` mask drops and list ranks at or past its
+    group's own ``kk`` (data, not shape) — and takes :func:`_merge_lists`
+    at ``k_top``; a group keeps its rows' first ``k_out`` columns,
+    bit-for-bit the solo merge of the lists cut to ``kk`` at ``k_out``
+    (the masked entries are +inf, and a list past its valid entries holds
+    only +inf, whose gid is -1 whichever entry is taken).  Shapes follow
+    (kind, kpad, tiles, k_top, m, mesh) and the arguments' shapes — padded
+    query rows and bucket geometry — never the group count, the per-group
+    k or the filter values."""
+    def scan(qp, xp, sp, pr):
+        def one(x, s):
+            return filtered_topk_kernel_call(qp, x, s, pr, kind=kind,
+                                             kpad=kpad, metric=metric,
+                                             tq=tq, tn=tn, m=m)
+        return jax.vmap(one)(xp, sp)
 
     scan = _on_mesh(scan, mesh, (P(), P("shard"), P("shard"), P()),
-                    (P(None, "shard"), P(None, "shard")))
+                    (P("shard"), P("shard")))
 
-    def call(qps, pjs, active, kk, xs, ss, gids):
+    def call(qp, pr, active, kk, xs, ss, gids):
         _TRACE_COUNT[0] += 1             # python side-effect: trace time only
         xp = _pad_to(_pad_to(xs, 2, 128, 0.0), 1, tn, 0.0)
         sp = _pad_to(_pad_to(ss, 2, 128, 0.0), 1, tn, _PAD_META)
-        dd, ids = scan(qps, xp, sp, pjs)
-        rank = jax.lax.broadcasted_iota(jnp.int32, ids.shape[1:], 2)
-
-        def merge(group):
-            g_ids, g_dd, g_active, g_kk = group
-            valid = (g_ids >= 0) & g_active[:, None, None] & (rank < g_kk)
-            return _merge_lists(g_ids, g_dd, valid, gids, k_top)
-        return jax.lax.map(merge, (ids, dd, active, kk))
+        dd, ids = scan(qp, xp, sp, pr)               # [rows, r_pad, kpad]
+        rank = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 2)
+        valid = ((ids >= 0) & jnp.transpose(active)[:, :, None]
+                 & (rank < kk[None, :, None]))
+        return _merge_lists(ids, dd, valid, gids, k_top)
     return jax.jit(call)
 
 
 def sharded_filtered_topk_grouped(qs, kind: str, params, ks, active, xs, ss,
-                                  gids, metric: str = "l2", tq: int = 64,
-                                  tn: int = 256, trace=None):
+                                  gids, m: int, metric: str = "l2",
+                                  tq: int = 64, tn: int = 256, trace=None):
     """One filter class of request groups against ONE ``[rows, cap, d]`` /
-    ``[rows, cap, m]`` shard stack, kernel and shard merge in one program.
+    ``[rows, cap, 128]`` shard stack, kernel and shard merge in one
+    program.
 
     ``qs`` holds each group's ``[bq_i, d]`` queries, ``params`` its packed
     ``[4, 128]`` filter (:func:`encode_filter`, all of kind ``kind``),
     ``ks`` its kernel width ``kk_i = min(k_i, cap)`` (all of one class:
     the same ``kpad = next_pow2(max(kk_i, 8))``) and ``active`` its
     ``[rows]`` temporal row mask; ``gids [rows, cap]`` maps shard-local ids
-    to global ones.  The inputs are built on the host in numpy (queries
-    zero-padded to the 128-lane width and to the widest group's padded
-    row count) and uploaded with one ``device_put``; the kernel dispatch
-    is a ``vmap`` of the solo one over the group axis, so each group's
-    lists are what its solo :func:`sharded_filtered_topk` call computes.
+    to global ones; ``m`` is the real metadata width.  The groups' rows
+    are stacked group-major into one query axis of ``R = sum(bq_i)`` rows,
+    zero-padded to a multiple of ``tq`` and to the 128-lane width, each
+    row carrying its group's filter, temporal mask and ``kk``; built on
+    the host in numpy and uploaded with one ``device_put``.  One kernel
+    call per shard then scans the block for every row of the class, and
+    each row's lists are what its group's solo
+    :func:`sharded_filtered_topk` call computes for it.
 
-    Returns device ``(gids [G, bq_pad, k_top], dists [G, bq_pad, k_top])``
-    (:func:`_grouped_k_top`): group ``i``'s answer is
-    ``[i, :bq_i, :k_out_i]`` with ``k_out_i = min(k_i, rows * kk_i)``,
-    bit-for-bit the solo ``sharded_filtered_topk`` + exact shard merge at
-    ``k_out_i``.  Nothing waits for the device.
+    Returns ``(gids [R_pad, k_top], dists [R_pad, k_top], offsets)``
+    (device arrays, :func:`_grouped_k_top`; ``offsets`` each group's first
+    row): group ``i``'s answer is ``[offsets[i]:offsets[i] + bq_i,
+    :k_out_i]`` with ``k_out_i = min(k_i, rows * kk_i)``, bit-for-bit the
+    solo ``sharded_filtered_topk`` + exact shard merge at ``k_out_i``.
+    Nothing waits for the device.
 
     ``trace`` (a ``repro.obs.trace.QueryTrace``, default off) times
     ``group_stack`` (the host build and upload) and ``kernel_launch``
@@ -576,20 +575,28 @@ def sharded_filtered_topk_grouped(qs, kind: str, params, ks, active, xs, ss,
     n_groups = len(qs)
     rows, cap = int(gids.shape[0]), int(gids.shape[1])
     kpad = _next_pow2(max(max(ks), 8))
-    with trace.span("group_stack", groups=n_groups):
+    sizes = np.asarray([int(q.shape[0]) for q in qs])
+    offsets = np.cumsum(sizes) - sizes
+    n_rows = int(sizes.sum())
+    with trace.span("group_stack", groups=n_groups, rows=n_rows):
         dpad = round_up(int(xs.shape[2]), 128)
-        bq_pad = round_up(max(int(q.shape[0]) for q in qs), tq)
-        qps = np.zeros((n_groups, bq_pad, dpad), np.float32)
-        for i, q in enumerate(qs):
-            qps[i, :q.shape[0], :q.shape[1]] = q
-        args = jax.device_put((qps, np.stack(params),
-                               np.stack(active).astype(bool),
-                               np.asarray(ks, np.int32)))
-    with trace.span("kernel_launch", groups=n_groups):
-        return _grouped_kernel_dispatch(
+        r_pad = round_up(n_rows, tq)
+        qp = np.zeros((r_pad, dpad), np.float32)
+        q_all = np.concatenate(qs)
+        qp[:n_rows, :q_all.shape[1]] = q_all
+        pr = np.zeros((r_pad,) + np.shape(params[0]), np.float32)
+        pr[:n_rows] = np.repeat(np.stack(params), sizes, axis=0)
+        act = np.zeros((r_pad, rows), bool)
+        act[:n_rows] = np.repeat(np.stack(active), sizes, axis=0)
+        kk = np.zeros((r_pad,), np.int32)
+        kk[:n_rows] = np.repeat(np.asarray(ks, np.int32), sizes)
+        args = jax.device_put((qp, pr, act, kk))
+    with trace.span("kernel_launch", groups=n_groups, rows=n_rows):
+        out_g, out_d = _grouped_kernel_dispatch(
             kind, kpad, metric, tq, max(tn, kpad),
-            _grouped_k_top(kpad, rows, cap), block_mesh(xs))(
+            _grouped_k_top(kpad, rows, cap), int(m), block_mesh(xs))(
                 *args, xs, ss, gids)
+    return out_g, out_d, offsets
 
 
 def quant_meta_rows(m: int) -> int:

@@ -545,10 +545,12 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
     (:func:`repro.distributed.segment_shards.pack_search_blocks_grouped`).
 
     Answers are **bit-for-bit** what per-group :func:`query_segments`
-    calls would return: the grouped kernel dispatch is a ``vmap`` of the
-    solo dispatch over the group axis, the bucket skip set per group
-    matches its solo temporal pruning, and each group merges with its own
-    ``k`` and temporal mask through the same exact ``(dist, gid)`` merge.
+    calls would return: the grouped kernel dispatch stacks a filter
+    class's groups in one query axis and tests each row against its own
+    group's filter with the solo predicate's float32 operations, the
+    bucket skip set per group matches its solo temporal pruning, and each
+    group merges with its own ``k`` and temporal mask through the same
+    exact ``(dist, gid)`` merge.
 
     The shared fast path requires a batchable configuration — bucketed
     sealed pack (``n_shards >= 1``, ``incremental_pack``), fp32 blocks
@@ -572,7 +574,10 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
     ``host_topk`` (the exact merge) and ``alive_filter``.  The counter
     ``grouped_device_merge_groups_total`` counts the groups the shared
     path finished on the device (its ratio to ``query_batches_total``
-    says how often that path engages).
+    says how often that path engages); ``grouped_kernel_rows_total`` and
+    ``grouped_kernel_row_slots_total`` count the real query rows its
+    class programs scanned and the padded rows their query tiles computed
+    (their ratio is the share of the query tile doing real work).
     Returns one ``QueryResult((gids [b_i, k_i], dists [b_i, k_i]))`` per
     group, in input order.
     """
@@ -642,6 +647,10 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
 
                 def on_cold(cap, stage_bytes, _reg=registry):
                     _reg.counter("tier_miss_total").inc()
+            def _kernel_rows(rows, slots, _reg=registry):
+                _reg.counter("grouped_kernel_rows_total").inc(rows)
+                _reg.counter("grouped_kernel_row_slots_total").inc(slots)
+
             pk_groups = [(qs[gi], groups[gi].filt, groups[gi].k,
                           bounds[gi][0], bounds[gi][1])
                          for gi in range(len(groups))]
@@ -655,7 +664,8 @@ def query_segments_grouped(manager, groups, trace=None, observe_group=None):
                     fault=lambda: manager._fault("query.bucket"),
                     observe_group=observe_group,
                     on_device_merge=registry.counter(
-                        "grouped_device_merge_groups_total").inc)
+                        "grouped_device_merge_groups_total").inc,
+                    on_kernel_rows=_kernel_rows)
             for gi, bl in enumerate(per):
                 for gg, dd in bl:
                     blocks_g[gi].append(gg)

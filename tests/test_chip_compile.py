@@ -80,16 +80,24 @@ def _compiled(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("kind", ["box", "box_ball"])
+@pytest.mark.parametrize("kind, per_row", [
+    pytest.param("box", False, id="box"),
+    pytest.param("box_ball", False, id="box_ball"),
+    pytest.param("box", True, id="box-per_row"),
+    pytest.param("box_ball", True, id="box_ball-per_row")])
 @pytest.mark.parametrize("kpad", [16, 64])
-def test_fused_fp32_kernel_compiles(mosaic, one_chip, kind, kpad):
+def test_fused_fp32_kernel_compiles(mosaic, one_chip, kind, per_row, kpad):
+    """One filter shared by the query tile, or one per query row (the
+    folded grouped path: params ``[TQ, 4, 128]``, 4 real metadata dims)."""
     from repro.kernels.filtered_topk import filtered_topk_kernel_call
     f = lambda q, x, s, p: filtered_topk_kernel_call(
-        q, x, s, p, kind=kind, kpad=kpad, tq=TQ, tn=TN, interpret=False)
+        q, x, s, p, kind=kind, kpad=kpad, tq=TQ, tn=TN,
+        m=4 if per_row else None, interpret=False)
+    params = (TQ, 4, 128) if per_row else (4, 128)
     c = _compiled(f, _spec((TQ, D), jnp.float32, one_chip),
                   _spec((65536, D), jnp.float32, one_chip),
                   _spec((65536, 128), jnp.float32, one_chip),
-                  _spec((4, 128), jnp.float32, one_chip))
+                  _spec(params, jnp.float32, one_chip))
     out = c.memory_analysis().output_size_in_bytes
     assert out <= 2 * TQ * max(128, 2 * kpad) * 4 + 4096
 
@@ -125,17 +133,19 @@ def test_beam_step_kernel_compiles(mosaic, one_chip, c):
 @pytest.mark.parametrize("grouped", [False, True])
 def test_bucket_dispatch_compiles(mosaic, one_chip, grouped):
     """The per-bucket vmapped dispatch over a [16, 65536, ·] block, solo
-    and with 4 request groups (kernel and shard merge in one program)."""
+    and for a filter class folded into one 64-row query tile, each row
+    with its own filter, temporal mask and k (kernel and shard merge in
+    one program)."""
     from repro.kernels import ops
     blk = (_spec((16, 65536, D), jnp.float32, one_chip),
            _spec((16, 65536, 128), jnp.float32, one_chip))
     if grouped:
         disp = ops._grouped_kernel_dispatch(
-            "box", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 65536))
-        args = (_spec((4, TQ, D), jnp.float32, one_chip),
-                _spec((4, 4, 128), jnp.float32, one_chip),
-                _spec((4, 16), jnp.bool_, one_chip),
-                _spec((4,), jnp.int32, one_chip), *blk,
+            "box", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 65536), 4)
+        args = (_spec((TQ, D), jnp.float32, one_chip),
+                _spec((TQ, 4, 128), jnp.float32, one_chip),
+                _spec((TQ, 16), jnp.bool_, one_chip),
+                _spec((TQ,), jnp.int32, one_chip), *blk,
                 _spec((16, 65536), jnp.int32, one_chip))
     else:
         disp = ops._sharded_kernel_dispatch("box", 16, "l2", TQ, TN)
@@ -147,9 +157,9 @@ def test_bucket_dispatch_compiles(mosaic, one_chip, grouped):
     assert re.search(r"^\s*%(vmap_)*jit_filtered_topk_kernel_call", text,
                      re.M)
     if grouped:
-        # the merge takes its k rounds without a sort: each group count
-        # compiles its own program, and a sort's code (about 300 KB on
-        # the chip) would stay in device memory once per program
+        # the merge takes its k rounds without a sort: each padded row
+        # count compiles its own program, and a sort's code (about 300 KB
+        # on the chip) would stay in device memory once per program
         assert not re.search(r"\bsort\(", text)
 
 
@@ -187,12 +197,12 @@ def test_grouped_dispatch_compiles_on_mesh(mosaic, mesh4):
     rows = NamedSharding(mesh4, P("shard"))
     rep = NamedSharding(mesh4, P())
     disp = ops._grouped_kernel_dispatch(
-        "box_ball", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 16384),
+        "box_ball", 16, "l2", TQ, TN, ops._grouped_k_top(16, 16, 16384), 4,
         mesh4)
-    c = _compiled(disp, _spec((4, TQ, D), jnp.float32, rep),
-                  _spec((4, 4, 128), jnp.float32, rep),
-                  _spec((4, 16), jnp.bool_, rep),
-                  _spec((4,), jnp.int32, rep),
+    c = _compiled(disp, _spec((TQ, D), jnp.float32, rep),
+                  _spec((TQ, 4, 128), jnp.float32, rep),
+                  _spec((TQ, 16), jnp.bool_, rep),
+                  _spec((TQ,), jnp.int32, rep),
                   _spec((16, 16384, D), jnp.float32, rows),
                   _spec((16, 16384, 128), jnp.float32, rows),
                   _spec((16, 16384), jnp.int32, rows))

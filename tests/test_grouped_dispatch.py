@@ -1,14 +1,16 @@
 """The grouped bucket dispatch finishes each filter class on the device.
 
 ``pack_search_blocks_grouped`` runs one program per (bucket, filter
-class): the fused kernel over the bucket's block for every group of the
-class, then each group's shard merge with its own ``k`` and temporal
+class): the class's query rows stacked in one query axis, each row with
+its group's filter, through one fused kernel call over the bucket's
+block, then each row's shard merge with its group's ``k`` and temporal
 mask.  The claims held here are equalities: every group's candidate
 blocks are bit-for-bit what a solo ``pack_search_blocks`` call returns
 for it, whatever the mix of k, filter kinds, query rows, temporal
-windows and deadlines; a second call with the same class shapes and other
-k values or filters traces nothing; and the manager counts the groups
-the on-device finish answered.
+windows and deadlines; a second call with the same padded class rows and
+other group counts, k values or filters traces nothing; and the manager
+counts the groups the on-device finish answered and the query rows its
+class programs scanned.
 """
 import dataclasses
 
@@ -112,6 +114,20 @@ CASES = {
                            (_q(23), make_polygon_filter(M, 0.6, seed=23),
                             10, -INF, INF),
                            (_q(24), _box(24), 10, -INF, INF)],
+    # 65 one-query groups of one class: their rows cross a 64-row tile
+    "rows_cross_tile": [(_q(100 + i, 1), _box(100 + i), 10, -INF, INF)
+                        for i in range(65)],
+    "mixed_rows_1_2_5": [(_q(25, 1), _ball(25), 10, -INF, INF),
+                         (_q(26, 2), _ball(26), 10, -INF, INF),
+                         (_q(27, 5), _ball(27), 10, -INF, INF),
+                         (_q(28, 2), _ball(28), 10, -INF, INF)],
+    # one-query groups sharing a tile: the second drops the first bucket's
+    # second row pair and the whole second bucket, the third keeps only
+    # the rows the second drops
+    "rows_drop_other_shards": [(_q(29, 1), _box(29), 10, -INF, INF),
+                               (_q(30, 1), _box(30), 10, 0.0, 1.5),
+                               (_q(31, 1), _box(31), 10, 1.5, 6.5),
+                               (_q(32, 1), _box(32), 10, 5.5, 6.5)],
 }
 
 
@@ -153,6 +169,8 @@ def test_grouped_blocks_bit_equal_solo(view, case):
     _assert_blocks_equal(got, _solo(view, groups, expire_after))
     if case == "temporal_masks":
         assert [len(b) for b in got] == [2, 1, 2]
+    if case == "rows_drop_other_shards":
+        assert [len(b) for b in got] == [2, 1, 2, 1]
 
 
 def test_grouped_blocks_bit_equal_solo_on_mesh():
@@ -165,9 +183,9 @@ def test_grouped_blocks_bit_equal_solo_on_mesh():
 
 
 def test_second_call_with_other_k_and_filters_traces_nothing(view):
-    """A class's program is keyed on its shapes (kind, kpad, groups,
-    padded rows, bucket geometry), never on the groups' k or filter
-    values: the same shapes with other k and filters trace nothing."""
+    """A class's program is keyed on its shapes (kind, kpad, padded rows,
+    bucket geometry), never on the groups' k or filter values: the same
+    shapes with other k and filters trace nothing."""
     first = [(_q(30), _box(30), 9, -INF, INF),
              (_q(31), _box(31), 16, -INF, INF),
              (_q(32), _ball(32), 10, -INF, INF)]
@@ -179,6 +197,19 @@ def test_second_call_with_other_k_and_filters_traces_nothing(view):
     got = pack_search_blocks_grouped(view, second)
     assert dispatch_trace_count() == t0
     _assert_blocks_equal(got, _solo(view, second, {}))
+
+
+def test_group_count_traces_nothing(view):
+    """The group count is no part of a class's program: 4 and then 32
+    one-query groups of one class fill one 64-row tile and run the same
+    program, so the second call traces nothing."""
+    four = [(_q(50 + i, 1), _box(50 + i), 10, -INF, INF) for i in range(4)]
+    many = [(_q(60 + i, 1), _box(60 + i), 10, -INF, INF) for i in range(32)]
+    pack_search_blocks_grouped(view, four)
+    t0 = dispatch_trace_count()
+    got = pack_search_blocks_grouped(view, many)
+    assert dispatch_trace_count() == t0
+    _assert_blocks_equal(got, _solo(view, many, {}))
 
 
 def _manager(**kw):
@@ -214,6 +245,30 @@ def test_device_merge_counter(quantize):
     assert _merged(mgr) == (len(groups) if quantize is None else 0)
     mgr.query(_q(45), _box(45), k=5)
     assert _merged(mgr) == (len(groups) if quantize is None else 0)
+
+
+def _counters(mgr):
+    return mgr.stats()["obs"]["metrics"]["counters"]
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_kernel_row_counters(quantize):
+    """``grouped_kernel_rows_total`` counts the real query rows the class
+    programs scanned and ``grouped_kernel_row_slots_total`` the padded
+    rows their tiles computed: 5 one-query groups of one class fill 5 of
+    each dispatched bucket's 64 rows.  A quantized pack takes the
+    per-group path and counts neither."""
+    mgr = _manager(quantize=quantize)
+    mgr.query_grouped([GroupQuery(_q(70 + i, 1), _box(70 + i), k=5)
+                       for i in range(5)])
+    c = _counters(mgr)
+    rows = c.get("grouped_kernel_rows_total", 0)
+    slots = c.get("grouped_kernel_row_slots_total", 0)
+    if quantize is not None:
+        assert rows == slots == 0
+        return
+    assert rows > 0 and rows % 5 == 0
+    assert rows / slots == 5 / 64
 
 
 def _lists(case, rows=3, b=4, w=8, cap=40):
